@@ -497,7 +497,9 @@ def main(argv: list[str] | None = None) -> int:
         findings, scanned = manager.check_changed()
     else:
         findings = manager.check_tree()
-    wall_s = round(time.monotonic() - t0, 3)
+    # microseconds: a tiny tree scans in under a millisecond, and a wall
+    # rounded to 0.0 would pass even a zero budget
+    wall_s = round(time.monotonic() - t0, 6)
     over_budget = (args.max_wall_s is not None and wall_s > args.max_wall_s)
 
     if args.update_baseline:

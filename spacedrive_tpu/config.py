@@ -70,7 +70,8 @@ class NodeConfig(VersionedConfig):
             "p2p_auto_accept_library": None,  # headless auto-pair target
             "features": [],
             # TPU-native: accelerator inventory advertised to peers
-            "accelerator": {"kind": None, "devices": 0, "mesh": []},
+            "accelerator": {"platform": None, "kind": None, "devices": 0,
+                            "mesh": []},
             "preferences": {},
         }
 

@@ -15,7 +15,7 @@ immediate return — no env lookup, no dict walk, nothing allocated. The
 plan is parsed once (at import from the environment, or by
 :func:`install`/:func:`reload` in tests and benches).
 
-The taxonomy the seams synthesize (transient vs fatal, and which layer
+The classification the seams synthesize (transient vs fatal, and which layer
 absorbs what) is documented in docs/architecture/robustness.md.
 """
 

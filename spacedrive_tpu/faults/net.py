@@ -85,7 +85,7 @@ _PARTITIONS = telemetry.gauge(
 
 class LinkDropped(ConnectionError):
     """The modeled link dropped this message (probabilistic loss). A
-    ``ConnectionError`` so the transient taxonomy retries it like a real
+    ``ConnectionError`` so the transient classification retries it like a real
     flap; the session resumes from its acknowledged watermark."""
 
 

@@ -11,8 +11,7 @@ A spec is a ``;``-separated list of rules, each ``seam:kind[:trigger]``:
   ``sync_apply`` (CRDT op materialization), ``sync_ingest`` (the receive
   path's admission check — kind ``overload`` synthesizes budget
   exhaustion there), ``p2p_send`` (outbound peer requests; kind ``busy``
-  synthesizes a peer's BUSY answer), ``relay_probe`` (the jax_guard relay
-  liveness check), ``chunk`` (the manifest stage: per-file payload reads
+  synthesizes a peer's BUSY answer), ``chunk`` (the manifest stage: per-file payload reads
   — inside the transient retry, so ``eio`` storms retry clean — and the
   CDC dispatch, where ``wedge`` exercises the chunk router's degrade
   ladder), ``manifest_commit`` (inside the identifier's transaction just
@@ -278,7 +277,7 @@ class FaultPlan:
             time.sleep(_stall_s())
             return
         if fired_rule.kind == "hang":
-            # the "never returns" failure mode (wedged tunnel, dead NFS):
+            # the "never returns" failure mode (hung device, dead NFS):
             # block far past any drain deadline; daemon stage threads die
             # with the process
             threading.Event().wait(HANG_S)

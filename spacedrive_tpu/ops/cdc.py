@@ -235,15 +235,16 @@ TILE_COLS = 128
 
 def _cdc_kernel(g_ref, mask_ref, out_ref):
     """One (TILE_ROWS, TILE_COLS) tile of boundary candidates. ``g_ref`` is
-    the haloed gear plane block (TILE_ROWS, 1, 2*TILE_COLS): local column
-    ``TILE_COLS + j`` is global position ``t*TILE_COLS + j``, so the k-th
-    window term for all 128 outputs is one static slice — 32 shifted adds,
-    all live values in vector registers, then the SMEM mask compare."""
-    g = g_ref[:, 0, :]
+    the haloed gear plane block (TILE_ROWS, 2*TILE_COLS) of tile ``t``:
+    local column ``TILE_COLS + j`` is global position ``t*TILE_COLS + j``,
+    so the k-th window term for all 128 outputs is one static slice — 32
+    shifted adds, all live values in vector registers, then the SMEM mask
+    compare."""
+    g = g_ref[...]
     h = jnp.zeros((TILE_ROWS, TILE_COLS), _u32)
     for k in range(WINDOW):
         h = h + (g[:, TILE_COLS - k : 2 * TILE_COLS - k] << _u32(k))
-    out_ref[:, 0, :] = jnp.where((h & mask_ref[0]) == 0, _u32(1), _u32(0))
+    out_ref[...] = jnp.where((h & mask_ref[0]) == 0, _u32(1), _u32(0))
 
 
 @jax.jit
@@ -252,26 +253,28 @@ def _candidates_pallas(g: jax.Array, lengths: jax.Array,
     B, L = g.shape  # B % TILE_ROWS == 0, L % TILE_COLS == 0 (caller pads)
     nt = L // TILE_COLS
     # materialize haloed tiles with one pad + gather-free slicing: tile t
-    # covers global columns [t*128 - 128, t*128 + 128)
+    # covers global columns [t*128 - 128, t*128 + 128). Tiles lead the
+    # layout (nt, B, 256) so each block's last two dims are (8, 256) —
+    # Mosaic tiles the last two dims of a block by (8, 128)
     gh = jnp.pad(g, ((0, 0), (TILE_COLS, 0)))
     tiles = jnp.stack(
-        [gh[:, t * TILE_COLS : (t + 2) * TILE_COLS] for t in range(nt)], axis=1
-    )  # (B, nt, 256)
+        [gh[:, t * TILE_COLS : (t + 2) * TILE_COLS] for t in range(nt)], axis=0
+    )  # (nt, B, 256)
     out = pl.pallas_call(
         _cdc_kernel,
         grid=(B // TILE_ROWS, nt),
         in_specs=[
-            pl.BlockSpec((TILE_ROWS, 1, 2 * TILE_COLS),
-                         lambda i, j: (i, j, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((None, TILE_ROWS, 2 * TILE_COLS),
+                         lambda i, j: (j, i, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((TILE_ROWS, 1, TILE_COLS),
-                               lambda i, j: (i, j, 0),
+        out_specs=pl.BlockSpec((None, TILE_ROWS, TILE_COLS),
+                               lambda i, j: (j, i, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((B, nt, TILE_COLS), _u32),
+        out_shape=jax.ShapeDtypeStruct((nt, B, TILE_COLS), _u32),
         interpret=interpret_mode(),
     )(tiles, jnp.asarray([mask], _u32).reshape(1))
-    cand = out.reshape(B, L) != 0
+    cand = out.transpose(1, 0, 2).reshape(B, L) != 0
     return cand & (jnp.arange(L)[None, :] < lengths[:, None])
 
 

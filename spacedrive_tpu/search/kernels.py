@@ -223,6 +223,21 @@ def _interpret() -> bool:
     return interpret_mode()
 
 
+def _widen(hay_ref, k: int):
+    """Plane ``k`` of the u8 block as i32: Mosaic on v5e has no i8 vector
+    compare or add, so the kernels compare in i32 registers (the needle
+    already rides SMEM as i32). The block in VMEM stays u8."""
+    import jax.numpy as jnp
+
+    return hay_ref[k].astype(jnp.int32)
+
+
+def _to_u8(mask):
+    import jax.numpy as jnp
+
+    return mask.astype(jnp.int32).astype(jnp.uint8)
+
+
 @functools.lru_cache(maxsize=256)
 def _substring_pallas_jit(nlen: int, width: int, interpret: bool):
     import jax
@@ -236,12 +251,11 @@ def _substring_pallas_jit(nlen: int, width: int, interpret: bool):
         # trace time; acc lives in vector registers across all offsets.
         acc = jnp.zeros((TILE_ROWS, LANES), dtype=jnp.bool_)
         for j in range(width - nlen + 1):
-            eq = hay_ref[j] == needle_ref[0, 0].astype(jnp.uint8)
+            eq = _widen(hay_ref, j) == needle_ref[0, 0]
             for k in range(1, nlen):
-                eq = eq & (hay_ref[j + k]
-                           == needle_ref[0, k].astype(jnp.uint8))
+                eq = eq & (_widen(hay_ref, j + k) == needle_ref[0, k])
             acc = acc | eq
-        out_ref[...] = acc.astype(jnp.uint8)
+        out_ref[...] = _to_u8(acc)
 
     def run(planes, needle):  # (W, R, 128) u8, (1, MAX_NEEDLE) i32
         rows = planes.shape[1]
@@ -272,10 +286,10 @@ def _exact_pallas_jit(width: int, interpret: bool):
     from jax.experimental.pallas import tpu as pltpu
 
     def kernel(needle_ref, hay_ref, out_ref):
-        eq = hay_ref[0] == needle_ref[0, 0].astype(jnp.uint8)
+        eq = _widen(hay_ref, 0) == needle_ref[0, 0]
         for k in range(1, width):
-            eq = eq & (hay_ref[k] == needle_ref[0, k].astype(jnp.uint8))
-        out_ref[...] = eq.astype(jnp.uint8)
+            eq = eq & (_widen(hay_ref, k) == needle_ref[0, k])
+        out_ref[...] = _to_u8(eq)
 
     def run(planes, padded):  # (W, R, 128) u8, (1, W) i32
         rows = planes.shape[1]
@@ -311,11 +325,13 @@ def _lex_pallas_jit(width: int, interpret: bool):
         gt = jnp.zeros((TILE_ROWS, LANES), dtype=jnp.bool_)
         lt = jnp.zeros((TILE_ROWS, LANES), dtype=jnp.bool_)
         for k in range(width):
-            b = bound_ref[0, k].astype(jnp.uint8)
+            b = bound_ref[0, k]
+            plane = _widen(hay_ref, k)
             undecided = ~gt & ~lt
-            gt = gt | (undecided & (hay_ref[k] > b))
-            lt = lt | (undecided & (hay_ref[k] < b))
-        out_ref[...] = gt.astype(jnp.uint8) + 2 * lt.astype(jnp.uint8)
+            gt = gt | (undecided & (plane > b))
+            lt = lt | (undecided & (plane < b))
+        out_ref[...] = (gt.astype(jnp.int32)
+                        + 2 * lt.astype(jnp.int32)).astype(jnp.uint8)
 
     def run(planes, bound):  # (W, R, 128) u8, (1, W) i32
         rows = planes.shape[1]

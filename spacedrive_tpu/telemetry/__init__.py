@@ -12,8 +12,7 @@ docs/architecture/observability.md): job lifecycle (queue wait, step
 latency, lane occupancy), every pipeline stage (busy/blocked/idle),
 hasher dispatch (batches/files/bytes → live files-per-sec and MFU via
 ops/roofline.py), utils/retry.py (attempts, backoff, budget
-exhaustion), the fault seams, sync ingest, and the relay
-probe/recapture path.
+exhaustion), the fault seams, and sync ingest.
 
 ``SD_TELEMETRY=off`` turns every record call into a no-op (one global
 read); spans still *measure* so job-report stage timings never depend
@@ -282,10 +281,6 @@ def _declare_core() -> None:
             "failure")
     counter("sd_quarantined_files_total",
             "per-item failures quarantined by the identifier")
-    counter("sd_relay_probe_total", "relay liveness probes by outcome",
-            labels=("outcome",))
-    counter("sd_relay_recovered_total",
-            "relay recoveries observed by the recapture watcher")
     # sync ingest families carry a bounded-cardinality ``peer`` label
     # (hash-truncated node id, "local" for transport-less ingest) so two
     # aggressive peers are distinguishable in one scrape

@@ -1,4 +1,4 @@
-"""The one retry-with-backoff policy (and the transient-error taxonomy).
+"""The one retry-with-backoff policy (and the transient-error classification).
 
 Every transient-failure class in the system retries through
 :func:`retry_call` with an explicit :class:`RetryPolicy` — never an ad-hoc
@@ -18,7 +18,7 @@ jobs|objects|sync|p2p). Three properties the scattered loops never had:
 Classification (transient vs fatal) lives here too so every layer agrees:
 SQLITE_BUSY, EINTR/EIO/EAGAIN reads, and connection flaps are retryable;
 vanished/permission-denied/truncated items are NOT — those quarantine at
-the item level (docs/architecture/robustness.md has the full taxonomy).
+the item level (docs/architecture/robustness.md has the full classification).
 """
 
 from __future__ import annotations
@@ -60,7 +60,7 @@ class RetryPolicy:
         return max(0.0, d)
 
 
-# -- the transient-vs-fatal taxonomy ------------------------------------------
+# -- the transient-vs-fatal classification ------------------------------------------
 
 #: OSError errnos that mean "the same call can succeed if repeated"
 TRANSIENT_ERRNOS = frozenset({errno.EINTR, errno.EIO, errno.EAGAIN,
@@ -79,18 +79,14 @@ def is_transient_io(exc: BaseException) -> bool:
     return isinstance(exc, OSError) and exc.errno in TRANSIENT_ERRNOS
 
 
-def is_relay_flap(exc: BaseException) -> bool:
-    """A refused/reset/timed-out probe of a service known to flap (the
-    device relay, a peer link) — retry before declaring it down."""
-    return isinstance(exc, (ConnectionError, TimeoutError))
-
-
 def is_transient(exc: BaseException) -> bool:
     """The union class: what retry_call retries by default. Exceptions can
     also self-classify via an ``sd_transient`` attribute (injected crash
-    markers, wedge errors)."""
+    markers, wedge errors). A refused/reset/timed-out link (a flapping
+    peer) is transient too: retry before declaring it down."""
     return (is_sqlite_busy(exc) or is_transient_io(exc)
-            or is_relay_flap(exc) or getattr(exc, "sd_transient", False))
+            or isinstance(exc, (ConnectionError, TimeoutError))
+            or getattr(exc, "sd_transient", False))
 
 
 def is_device_wedge(exc: BaseException) -> bool:

@@ -146,7 +146,6 @@ def child_env() -> dict:
     env.update({
         "JAX_PLATFORMS": "cpu", "SD_NO_WATCHER": "1", "SD_P2P_DISABLED": "1",
         "SD_NO_ACCEL_PROBE": "1", "SD_COMMIT_GROUP": str(COMMIT_GROUP),
-        "SD_OPPORTUNISTIC_BENCH": "",
     })
     env.pop("SD_FAULTS", None)  # kills are armed in-process, post-seed
     return env

@@ -138,19 +138,19 @@ def test_dryrun_multichip_pallas_interpret():
     assert "dryrun_multichip OK" in proc.stdout
 
 
-def test_roofline_mfu_model(monkeypatch):
+def test_roofline_mfu_model():
+    from types import SimpleNamespace
+
     from spacedrive_tpu.ops import roofline
 
     assert roofline.OPS_PER_BYTE == 12.5
-    monkeypatch.delenv("SD_TPU_PEAK_U32_OPS", raising=False)
-    peak = roofline.peak_u32_ops()
-    assert peak == roofline.DEFAULT_PEAK_U32_OPS
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    peak = roofline.peak(v5e)
+    # published 197 TFLOP/s over 4 MXUs of 128x128 MACs: a ~1.5 GHz clock
+    assert peak.clock_hz == pytest.approx(1.503e9, rel=1e-3)
+    assert peak.u32_ops == pytest.approx(8 * 128 * 4 * peak.clock_hz)
+    roof = peak.u32_ops / roofline.OPS_PER_BYTE
     # the full roofline rate maps to MFU 1.0; half rate to 0.5
-    assert roofline.mfu(roofline.roofline_bytes_per_sec()) == pytest.approx(1.0)
-    assert roofline.mfu(roofline.roofline_bytes_per_sec() / 2) == pytest.approx(0.5)
-    assert roofline.mfu(0) == 0.0
-    monkeypatch.setenv("SD_TPU_PEAK_U32_OPS", "1e12")
-    assert roofline.peak_u32_ops() == 1e12
-    assert roofline.mfu(4e10) == pytest.approx(0.5)
-    monkeypatch.setenv("SD_TPU_PEAK_U32_OPS", "junk")
-    assert roofline.peak_u32_ops() == roofline.DEFAULT_PEAK_U32_OPS
+    assert roofline.mfu(roof, v5e) == pytest.approx(1.0)
+    assert roofline.mfu(roof / 2, v5e) == pytest.approx(0.5)
+    assert roofline.mfu(0, v5e) == 0.0

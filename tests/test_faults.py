@@ -549,7 +549,7 @@ def test_sync_apply_crash_falls_back_to_careful_pass(tmp_path, clean_faults):
 # -- hasher degradation ladder -------------------------------------------------
 
 
-def test_hybrid_degrade_flips_verdict_and_recapture_resets(monkeypatch):
+def test_hybrid_degrade_flips_verdict():
     h = hasher_mod.HybridHasher()
     h._cpu_rate, h._device_rate = 10.0, 99.0
     h.router.seed(10.0, 99.0)
@@ -557,10 +557,29 @@ def test_hybrid_degrade_flips_verdict_and_recapture_resets(monkeypatch):
     h.degrade_device("unit")
     assert h._device_rate == 0.0 and h._cpu_rate == 10.0
     assert h.router.current == "cpu" and h.router.degraded
-    monkeypatch.setattr(hasher_mod, "_instances", {"hybrid": h})
-    hasher_mod.reset_device_verdicts()
-    assert h._cpu_rate is None and h._device_rate is None
-    assert not h.router.degraded and h.router.cpu_bps is None
+
+
+def test_bounded_call_does_not_count_compile_time(monkeypatch):
+    """A cold compile is set-up, not a slow device: time the worker spends
+    compiling leaves the deadline untouched; time outside it counts."""
+    import threading
+
+    compiling = threading.Event()
+    compiling.set()
+    monkeypatch.setattr(hasher_mod, "_compiling",
+                        lambda _t: compiling.is_set())
+
+    def slow_compile_then_run():
+        time.sleep(0.6)  # "compiling": well past the 0.2 s deadline
+        compiling.clear()
+        time.sleep(0.05)
+        return "digests"
+
+    assert hasher_mod._bounded_call(slow_compile_then_run, 0.2, "t",
+                                    poll_s=0.05) == ("ok", "digests")
+    # no compile in sight: the same deadline times the call out
+    assert hasher_mod._bounded_call(lambda: time.sleep(0.6), 0.2, "t",
+                                    poll_s=0.05) == ("timeout", None)
 
 
 def test_router_reprobes_device_after_bounded_cpu_batches():

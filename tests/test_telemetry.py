@@ -171,7 +171,7 @@ def test_span_tree_shape_and_stage_reconciliation(span_tree_scan):
     assert sum(c.get("attrs", {}).get("pages", 0) for c in commits) == batches
     # stage spans are children of the job's pipeline.run span — including
     # page/hash, which open on OTHER threads and pin the run span as
-    # their explicit parent (the documented taxonomy, observability.md)
+    # their explicit parent (the documented classification, observability.md)
     runs = _spans_named(tree, "pipeline.run")
     assert len(runs) == 1
     run_children = {c["name"] for c in runs[0]["children"]}
