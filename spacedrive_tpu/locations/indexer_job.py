@@ -15,6 +15,7 @@ import uuid
 from pathlib import Path
 from typing import Any
 
+from .. import telemetry
 from ..jobs import EarlyFinish, JobError, StatefulJob, StepResult, WorkerContext
 from ..models import FilePath, Location, utc_now
 from .rules import CompiledRules, rules_for_location
@@ -58,14 +59,19 @@ class IndexerJob(StatefulJob):
             raise JobError(f"location {self.init_args['location_id']} not found")
         return row
 
-    def _steps_from_walk(self, result: WalkResult) -> tuple[list[dict], dict]:
+    def _steps_from_walk(self, ctx: WorkerContext,
+                         result: WalkResult) -> tuple[list[dict], dict]:
         steps: list[dict] = []
-        for batch in _batches([_entry_to_row(e) for e in result.walked], BATCH_SIZE):
+        with telemetry.span(getattr(ctx, "trace", None), "indexer.rows",
+                            files=len(result.walked) + len(result.to_update)):
+            saves = [_entry_to_row(e) for e in result.walked]
+            updates = [
+                {**_entry_to_row(e), "row_id": e.row_id,
+                 "content_changed": e.content_changed}
+                for e in result.to_update
+            ]
+        for batch in _batches(saves, BATCH_SIZE):
             steps.append({"kind": "save", "rows": batch})
-        updates = [
-            {**_entry_to_row(e), "row_id": e.row_id, "content_changed": e.content_changed}
-            for e in result.to_update
-        ]
         for batch in _batches(updates, BATCH_SIZE):
             steps.append({"kind": "update", "rows": batch})
         if result.to_remove:
@@ -89,13 +95,14 @@ class IndexerJob(StatefulJob):
         sub_path = self.init_args.get("sub_path") or ""
         rules = CompiledRules(rules_for_location(ctx.library.db, location["id"]))
         t0 = time.perf_counter()
-        result = walk(
-            location["id"], location_path, rules,
-            db_fetcher_for(ctx.library.db, location["id"]),
-            sub_path=sub_path, limit=WALK_LIMIT,
-        )
+        with telemetry.span(getattr(ctx, "trace", None), "indexer.walk"):
+            result = walk(
+                location["id"], location_path, rules,
+                db_fetcher_for(ctx.library.db, location["id"]),
+                sub_path=sub_path, limit=WALK_LIMIT,
+            )
         scan_time = time.perf_counter() - t0
-        steps, meta = self._steps_from_walk(result)
+        steps, meta = self._steps_from_walk(ctx, result)
         meta["scan_read_time"] = scan_time
         meta["db_write_time"] = 0.0
         if not steps:
@@ -107,11 +114,12 @@ class IndexerJob(StatefulJob):
                      step_number: int) -> StepResult:
         db = ctx.library.db
         kind = step["kind"]
+        trace = getattr(ctx, "trace", None)
         t0 = time.perf_counter()
         sync = getattr(ctx.library, "sync", None)
         emit = sync is not None and getattr(sync, "emit_messages", False)
         if kind == "save":
-            with db.transaction():
+            with telemetry.span(trace, "indexer.save"), db.transaction():
                 # or_ignore: a watcher may have raced us (unique indexes hold)
                 db.insert_many(FilePath, step["rows"], or_ignore=True)
                 if emit:
@@ -122,7 +130,7 @@ class IndexerJob(StatefulJob):
                                         "saved_rows": len(step["rows"])})
         if kind == "update":
             ops = []
-            with db.transaction():
+            with telemetry.span(trace, "indexer.save"), db.transaction():
                 for row in step["rows"]:
                     values = {
                         # renames carry the new identity fields; updates by row id
@@ -155,7 +163,7 @@ class IndexerJob(StatefulJob):
                                         "updated_rows": len(step["rows"])})
         if kind == "remove":
             ops = []
-            with db.transaction():
+            with telemetry.span(trace, "indexer.save"), db.transaction():
                 for fp_id in step["ids"]:
                     if emit:
                         row = db.find_one(FilePath, {"id": fp_id})
@@ -170,13 +178,14 @@ class IndexerJob(StatefulJob):
         if kind == "walk":
             location = self._location(ctx)
             rules = CompiledRules(rules_for_location(db, location["id"]))
-            result = walk(
-                location["id"], data["location_path"], rules,
-                db_fetcher_for(db, location["id"]),
-                sub_path=step["dir"], limit=WALK_LIMIT,
-                include_root=False,
-            )
-            more_steps, meta = self._steps_from_walk(result)
+            with telemetry.span(trace, "indexer.walk"):
+                result = walk(
+                    location["id"], data["location_path"], rules,
+                    db_fetcher_for(db, location["id"]),
+                    sub_path=step["dir"], limit=WALK_LIMIT,
+                    include_root=False,
+                )
+            more_steps, meta = self._steps_from_walk(ctx, result)
             meta["scan_read_time"] = time.perf_counter() - t0
             return StepResult(more_steps=more_steps, metadata=meta)
         raise JobError(f"unknown indexer step kind: {kind}")

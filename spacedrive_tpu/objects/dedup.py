@@ -15,6 +15,7 @@ from typing import TYPE_CHECKING, Any
 
 import numpy as np
 
+from .. import telemetry
 from ..jobs import EarlyFinish, StatefulJob, StepResult, WorkerContext
 from ..models import FilePath
 from .cas import MINIMUM_FILE_SIZE, SAMPLED_MESSAGE_LEN
@@ -55,37 +56,44 @@ def _paths_of(db, rows_db) -> tuple[list[str], list[int]]:
 def _signatures(paths: list[str], sizes: list[int],
                 errors: list[str]) -> tuple[np.ndarray, np.ndarray]:
     """(n, K) uint32 MinHash signatures + lengths, computed in SIG_BATCH
-    device passes so corpus size never explodes host/device memory."""
+    device passes so corpus size never explodes host/device memory. Each
+    pass is a ``dedup.gather`` span (the sample reads) and a
+    ``dedup.minhash`` span (H2D, kernel, readback)."""
     import jax
 
     from ..ops.minhash import K, minhash_rows
 
+    trace = telemetry.current_trace()
     n = len(paths)
     sigs = np.zeros((n, K), np.uint32)
     lengths = np.zeros(n, np.int32)
     for start in range(0, n, SIG_BATCH):
         stop = min(n, start + SIG_BATCH)
         cnt = stop - start
-        buf = np.zeros((cnt, SAMPLED_STRIDE), np.uint8)
-        lens = np.zeros(cnt, np.int32)
-        try:
-            from ..native import cas_native
+        with telemetry.span(trace, "dedup.gather", files=cnt):
+            buf = np.zeros((cnt, SAMPLED_STRIDE), np.uint8)
+            lens = np.zeros(cnt, np.int32)
+            try:
+                from ..native import cas_native
 
-            cas_native.gather_batch(paths[start:stop], sizes[start:stop],
-                                    buf, lens)
-        except Exception:
-            from .cas import read_sampled_batch
+                cas_native.gather_batch(paths[start:stop], sizes[start:stop],
+                                        buf, lens)
+            except Exception:
+                from .cas import read_sampled_batch
 
-            msgs = read_sampled_batch(paths[start:stop], sizes[start:stop])
-            for i, m in enumerate(msgs):
-                if isinstance(m, Exception):
-                    errors.append(f"{paths[start + i]}: {m}")
-                    continue
-                buf[i, : len(m)] = np.frombuffer(m, np.uint8)
-                lens[i] = len(m)
-        sigs[start:stop] = np.asarray(minhash_rows(
-            jax.device_put(buf.view(np.uint32).reshape(cnt, SAMPLED_STRIDE // 4)),
-            jax.device_put(lens)))
+                msgs = read_sampled_batch(paths[start:stop],
+                                          sizes[start:stop])
+                for i, m in enumerate(msgs):
+                    if isinstance(m, Exception):
+                        errors.append(f"{paths[start + i]}: {m}")
+                        continue
+                    buf[i, : len(m)] = np.frombuffer(m, np.uint8)
+                    lens[i] = len(m)
+        with telemetry.span(trace, "dedup.minhash", files=cnt):
+            sigs[start:stop] = np.asarray(minhash_rows(
+                jax.device_put(buf.view(np.uint32).reshape(
+                    cnt, SAMPLED_STRIDE // 4)),
+                jax.device_put(lens)))
         lengths[start:stop] = lens
     errors += [paths[i] for i in range(n) if lengths[i] == 0]
     return sigs, lengths
@@ -106,15 +114,20 @@ def find_near_duplicates(library: "Library", location_id: int | None = None,
     ensure_jax_safe()  # the one device gate (analysis jax-wedge pass)
     from ..ops.minhash import K
 
+    # per-pass spans under the caller's open span (the detector job's
+    # job.step); a bare timer outside a job
+    trace = telemetry.current_trace()
     db = library.db
     where = "is_dir = 0 AND size_in_bytes > ?"
     params: list[Any] = [MINIMUM_FILE_SIZE]
     if location_id is not None:
         where += " AND location_id = ?"
         params.append(location_id)
-    rows_db = [FilePath.decode_row(r) for r in db.query(
-        f"SELECT * FROM file_path WHERE {where} ORDER BY id LIMIT ?",
-        params + [limit])]
+    with telemetry.span(trace, "dedup.select"):
+        rows_db = [FilePath.decode_row(r) for r in db.query(
+            f"SELECT * FROM file_path WHERE {where} ORDER BY id LIMIT ?",
+            params + [limit])]
+        paths, sizes = _paths_of(db, rows_db)
     n = len(rows_db)
     if n < 2:
         return {"groups": [], "pairs": [], "pair_count": 0, "scanned": n,
@@ -123,7 +136,6 @@ def find_near_duplicates(library: "Library", location_id: int | None = None,
         method = "all_pairs" if n <= ALL_PAIRS_LIMIT else "banded"
 
     errors: list[str] = []
-    paths, sizes = _paths_of(db, rows_db)
     sigs, lengths = _signatures(paths, sizes, errors)
     thr_k = max(1, int(threshold * K))
 
@@ -142,6 +154,17 @@ def find_near_duplicates(library: "Library", location_id: int | None = None,
     else:
         raw_pairs, pair_count = _all_pairs(sigs, lengths > 0, thr_k)
 
+    with telemetry.span(trace, "dedup.verify"):
+        return _group(rows_db, raw_pairs, pair_count, errors, method)
+
+
+def _group(rows_db: list[dict], raw_pairs: list[tuple[int, int, int]],
+           pair_count: int, errors: list[str], method: str) -> dict[str, Any]:
+    """Verified pairs → the result: union-find groups and each row's best
+    match."""
+    from ..ops.minhash import K
+
+    n = len(rows_db)
     # union-find grouping from verified pairs
     parent = list(range(n))
 
@@ -184,27 +207,32 @@ def find_near_duplicates(library: "Library", location_id: int | None = None,
 def _all_pairs(sigs: np.ndarray, valid_rows: np.ndarray,
                thr_k: int) -> tuple[list[tuple[int, int, int]], int]:
     """Device all-pairs sweep → each flagged row's best earlier match as
-    verified (i, j, matches), and the sweep's count of all similar pairs."""
+    verified (i, j, matches), and the sweep's count of all similar pairs.
+    Spans: ``dedup.sweep`` (pad, kernel, readback), then ``dedup.verify``
+    (the host best-match loop)."""
     import jax
 
     from ..ops.minhash import pad_for_blocks, similar_pairs_count
 
+    trace = telemetry.current_trace()
     n = sigs.shape[0]
-    sigs_p, valid = pad_for_blocks(sigs)
-    valid[:n] &= valid_rows
-    total, dup = similar_pairs_count(jax.device_put(sigs_p),
-                                     jax.device_put(valid), thr_k)
-    dup = np.asarray(dup)[:n]
+    with telemetry.span(trace, "dedup.sweep", files=n):
+        sigs_p, valid = pad_for_blocks(sigs)
+        valid[:n] &= valid_rows
+        total, dup = similar_pairs_count(jax.device_put(sigs_p),
+                                         jax.device_put(valid), thr_k)
+        total, dup = int(total), np.asarray(dup)[:n]
     out: list[tuple[int, int, int]] = []
-    for i in range(n):
-        if not dup[i]:
-            continue
-        eq = (sigs[i][None, :] == sigs[:i]).sum(axis=1)
-        eq[~valid_rows[:i]] = 0
-        j = int(np.argmax(eq))
-        if eq[j] >= thr_k:
-            out.append((j, i, int(eq[j])))
-    return out, int(total)
+    with telemetry.span(trace, "dedup.verify"):
+        for i in range(n):
+            if not dup[i]:
+                continue
+            eq = (sigs[i][None, :] == sigs[:i]).sum(axis=1)
+            eq[~valid_rows[:i]] = 0
+            j = int(np.argmax(eq))
+            if eq[j] >= thr_k:
+                out.append((j, i, int(eq[j])))
+    return out, total
 
 
 def _banded_pairs(sigs: np.ndarray, valid_rows: np.ndarray, thr_k: int,
@@ -328,7 +356,8 @@ class DedupDetectorJob(StatefulJob):
                          "file_path_b_id": max(a, b),
                          "similarity": pair["similarity"],
                          "date_detected": utc_now()})
-        with db.transaction():
+        with telemetry.span(getattr(ctx, "trace", None), "dedup.persist",
+                            pairs=len(rows)), db.transaction():
             # rescan refreshes the location's pair set
             db.query(
                 "DELETE FROM near_duplicate WHERE file_path_a_id IN "

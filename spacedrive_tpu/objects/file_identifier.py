@@ -220,12 +220,13 @@ class FileIdentifierJob(StatefulJob):
         # The speculative cursor rides in ``scratch``: rows at id <= cursor
         # are untouched by later commits, so speculative pages see exactly
         # the row sets the sequential loop would
-        rows = [dict(r) for r in db.query(
-            f"SELECT id, pub_id, name, extension, materialized_path, is_dir, "
-            f"size_in_bytes, date_created FROM file_path "
-            f"WHERE {where} AND id > ? ORDER BY id LIMIT ?",
-            params + [cursor, _page_limit(scratch)],
-        )]
+        with telemetry.span(getattr(ctx, "trace", None), "identifier.select"):
+            rows = [dict(r) for r in db.query(
+                f"SELECT id, pub_id, name, extension, materialized_path, "
+                f"is_dir, size_in_bytes, date_created FROM file_path "
+                f"WHERE {where} AND id > ? ORDER BY id LIMIT ?",
+                params + [cursor, _page_limit(scratch)],
+            )]
         if not rows:
             return None
         scratch["cursor"] = rows[-1]["id"]
@@ -293,10 +294,11 @@ class FileIdentifierJob(StatefulJob):
         cursor = scratch.get("cursor", data["cursor"])
         where, params = _orphan_where(data["location_id"],
                                       data.get("sub_path"))
-        ids = [r["id"] for r in db.query(
-            f"SELECT id FROM file_path WHERE {where} AND id > ? "
-            f"ORDER BY id LIMIT ?",
-            params + [cursor, _page_limit(scratch)])]
+        with telemetry.span(getattr(ctx, "trace", None), "identifier.select"):
+            ids = [r["id"] for r in db.query(
+                f"SELECT id FROM file_path WHERE {where} AND id > ? "
+                f"ORDER BY id LIMIT ?",
+                params + [cursor, _page_limit(scratch)])]
         if not ids:
             return None
         scratch["cursor"] = ids[-1]
@@ -315,11 +317,12 @@ class FileIdentifierJob(StatefulJob):
         db = ctx.library.db
         where, params = _orphan_where(data["location_id"],
                                       data.get("sub_path"))
-        rows = [dict(r) for r in db.query(
-            f"SELECT id, pub_id, name, extension, materialized_path, is_dir, "
-            f"size_in_bytes, date_created FROM file_path "
-            f"WHERE {where} AND id >= ? AND id <= ? ORDER BY id",
-            params + [part["lo"], part["hi"]])]
+        with telemetry.span(getattr(ctx, "trace", None), "identifier.select"):
+            rows = [dict(r) for r in db.query(
+                f"SELECT id, pub_id, name, extension, materialized_path, "
+                f"is_dir, size_in_bytes, date_created FROM file_path "
+                f"WHERE {where} AND id >= ? AND id <= ? ORDER BY id",
+                params + [part["lo"], part["hi"]])]
         hashable, empty, messages, gather_s = \
             self._gather_rows(ctx, data, rows)
         return {"hashable": hashable, "empty": empty, "messages": messages,

@@ -45,11 +45,10 @@ SAMPLED_CHUNKS = (SAMPLED_MESSAGE_LEN + 1023) // 1024  # 57
 
 # -- dispatch telemetry --------------------------------------------------------
 # Per-batch accounting on the unified registry: batches/files/payload-bytes
-# per backend, plus the live files-per-sec / bytes-per-sec / MFU gauges the
-# roofline model turns the last batch into. The decorators guard with a
-# thread-local "outermost" flag so composed backends (hybrid → cpu/tpu,
-# remote → hybrid fallback) count each batch exactly once, attributed to
-# the entry-point backend.
+# per backend, plus the live files-per-sec / bytes-per-sec gauges of the
+# last batch. The decorators guard with a thread-local "outermost" flag so
+# composed backends (hybrid → cpu/tpu, remote → hybrid fallback) count
+# each batch exactly once, attributed to the entry-point backend.
 
 _HASH_BATCHES = telemetry.counter(
     "sd_hash_batches_total", "hash batches dispatched per backend",
@@ -66,10 +65,6 @@ _HASH_RATE = telemetry.gauge(
     "sd_hash_files_per_sec", "files/s of the last hash batch")
 _HASH_BPS = telemetry.gauge(
     "sd_hash_bytes_per_sec", "payload bytes/s of the last hash batch")
-_HASH_MFU = telemetry.gauge(
-    "sd_hash_mfu",
-    "u32-VPU model-op-utilization of the last hash batch "
-    "(ops/roofline.py model)")
 
 # -- per-batch router telemetry ------------------------------------------------
 # The hybrid router's decision inputs and outcomes: live transfer-inclusive
@@ -81,9 +76,6 @@ _ROUTER_BPS = telemetry.gauge(
     "sd_hash_router_bytes_per_sec",
     "EWMA transfer-inclusive payload bytes/s per engine (router input)",
     labels=("backend",))
-_ROUTER_MFU = telemetry.gauge(
-    "sd_hash_router_device_mfu",
-    "u32-VPU MFU implied by the router's device-engine EWMA rate")
 _ROUTER_FLIPS = telemetry.counter(
     "sd_hash_router_flips_total",
     "engine flips by the per-batch hash router (hysteresis-damped)")
@@ -130,30 +122,8 @@ def _record_hash(backend: str, files: int, nbytes: int, seconds: float) -> None:
     _HASH_BYTES.inc(nbytes, backend=backend)
     _HASH_SECONDS.observe(seconds, backend=backend)
     if seconds > 0:
-        bps = nbytes / seconds
         _HASH_RATE.set(round(files / seconds, 1))
-        _HASH_BPS.set(round(bps, 1))
-        if backend in ("tpu", "tpu-sharded"):  # wholly device batches
-            _set_mfu(_HASH_MFU, bps)
-
-
-def _set_mfu(gauge, bps: float) -> None:
-    """MFU of a device-engine rate against the chip's roofline; the gauge
-    stays unset on the CPU and on a chip kind without published peaks."""
-    from ..utils.jax_guard import ensure_jax_safe
-
-    if not ensure_jax_safe():
-        return
-    import jax
-
-    from ..ops import roofline
-
-    try:
-        value = roofline.mfu(bps, jax.devices()[0])
-    except roofline.UnknownDeviceKind as e:
-        logger.debug("no MFU: %s", e)
-        return
-    gauge.set(round(value, 6))
+        _HASH_BPS.set(round(nbytes / seconds, 1))
 
 
 def _instrumented(bytes_of: Callable[[tuple], int]):
@@ -294,22 +264,31 @@ class TpuHasher:
 
         stride = SAMPLED_CHUNKS * 1024
         pending = None  # (device result, host lengths, batch indices)
+        # per sub-batch spans under the caller's open span (the dispatch
+        # thread's pipeline.hash): pack = native gather into the rows,
+        # put = H2D enqueue, wait = kernel enqueue + digest readback
+        trace = telemetry.current_trace()
 
         def stage(idxs):
             """Host gather + device staging for one sub-batch (enqueued
             H2D overlaps whatever kernel is currently running)."""
             tier = self._pad_lanes(_pad_to_tier(len(idxs)))
-            rows = np.zeros((tier, stride), np.uint8)
-            lengths = np.zeros(tier, np.int32)
-            cas_native.gather_batch([paths[i] for i in idxs],
-                                    [sizes[i] for i in idxs], rows, lengths)
-            rows32 = rows.view(np.uint32).reshape(tier, stride // 4)
-            dev_rows, dev_lengths = self._stage_rows(rows32, lengths)
+            with telemetry.span(trace, "hash.pack", files=len(idxs)):
+                rows = np.zeros((tier, stride), np.uint8)
+                lengths = np.zeros(tier, np.int32)
+                cas_native.gather_batch([paths[i] for i in idxs],
+                                        [sizes[i] for i in idxs], rows,
+                                        lengths)
+                rows32 = rows.view(np.uint32).reshape(tier, stride // 4)
+            with telemetry.span(trace, "hash.put"):
+                dev_rows, dev_lengths = self._stage_rows(rows32, lengths)
             return (dev_rows, dev_lengths, lengths, idxs)
 
         def collect(item):
             dev, lengths, idxs = item
-            hexes = digests_to_hex(np.asarray(dev))
+            with telemetry.span(trace, "hash.wait"):
+                digests = np.asarray(dev)
+            hexes = digests_to_hex(digests)
             for j, i in enumerate(idxs):
                 if lengths[j] == 0:
                     out[i] = OSError(f"cas gather failed for {paths[i]}")
@@ -322,7 +301,8 @@ class TpuHasher:
         for nxt in chunks[1:] + [None]:
             dev_rows, dev_lengths, lengths, idxs = staged
             # enqueue batch k's kernel (async jax dispatch) ...
-            dev = self._device_hash_rows(dev_rows, dev_lengths)
+            with telemetry.span(trace, "hash.wait"):
+                dev = self._device_hash_rows(dev_rows, dev_lengths)
             # ... then gather + H2D-stage batch k+1 while it runs ...
             staged = stage(nxt) if nxt is not None else None
             # ... and only now block on batch k-1's D2H digest readback
@@ -463,8 +443,7 @@ class BackendRouter:
       whole scan.
 
     Decisions and inputs are published on the unified registry
-    (``sd_hash_router_*``); MFU for the device EWMA comes from
-    ops/roofline.py.
+    (``sd_hash_router_*``).
     """
 
     HYSTERESIS = 1.25
@@ -476,8 +455,7 @@ class BackendRouter:
     PROBE_SLICE = 128
 
     def __init__(self, flips_counter=None, batches_counter=None,
-                 bps_gauge=None, mfu_gauge=None,
-                 event_prefix: str = "hash_router") -> None:
+                 bps_gauge=None, event_prefix: str = "hash_router") -> None:
         # metric handles default to the hash families; other subsystems
         # (the device search engine, ISSUE 15) reuse the routing logic
         # with their own sd_* families and event names
@@ -486,8 +464,6 @@ class BackendRouter:
         self._batches_counter = batches_counter \
             if batches_counter is not None else _ROUTER_BATCHES
         self._bps_gauge = bps_gauge if bps_gauge is not None else _ROUTER_BPS
-        self._mfu_gauge = mfu_gauge if mfu_gauge is not None else (
-            _ROUTER_MFU if event_prefix == "hash_router" else None)
         self._event_prefix = event_prefix
         self._lock = threading.Lock()
         self.cpu_bps: float | None = None
@@ -591,8 +567,6 @@ class BackendRouter:
                 self.cpu_bps = ewma
         self._batches_counter.inc(backend=engine)
         self._bps_gauge.set(round(ewma, 1), backend=engine)
-        if engine == "device" and self._mfu_gauge is not None:
-            _set_mfu(self._mfu_gauge, ewma)
 
 
 class HybridHasher:
@@ -914,10 +888,17 @@ class ShardedHasher(TpuHasher):
 
         B = len(msgs)
         target = pad_batch_for_mesh(_pad_to_tier(B), self._mesh)
-        words, lengths = pack_messages(msgs + [b""] * (target - B), cap)
         fn = sharded_hasher(self._mesh)
-        out = digests_to_hex(np.asarray(fn(jnp.asarray(words), jnp.asarray(lengths))))
-        return out[:B]
+        # the same hash.pack / hash.put / hash.wait spans as
+        # blake3_batch_hex, the single-device form of this call
+        trace = telemetry.current_trace()
+        with telemetry.span(trace, "hash.pack", files=B):
+            words, lengths = pack_messages(msgs + [b""] * (target - B), cap)
+        with telemetry.span(trace, "hash.put"):
+            words, lengths = jnp.asarray(words), jnp.asarray(lengths)
+        with telemetry.span(trace, "hash.wait"):
+            digests = np.asarray(fn(words, lengths))
+        return digests_to_hex(digests)[:B]
 
 
 _BACKENDS: dict[str, Callable[[], HasherBackend]] = {

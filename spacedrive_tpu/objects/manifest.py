@@ -68,7 +68,7 @@ _CHUNK_ROUTER_BATCHES = telemetry.counter(
 #: separate EWMAs (CDC arithmetic intensity is nothing like BLAKE3's)
 router = BackendRouter(flips_counter=_CHUNK_ROUTER_FLIPS,
                        batches_counter=_CHUNK_ROUTER_BATCHES,
-                       bps_gauge=_CHUNK_ROUTER_BPS, mfu_gauge=None,
+                       bps_gauge=_CHUNK_ROUTER_BPS,
                        event_prefix="chunk_router")
 
 #: transient payload-read retries (same shape as cas.GATHER_RETRY): an

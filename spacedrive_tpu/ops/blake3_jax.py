@@ -46,6 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .. import telemetry
+
 # spec constants: the oracle is the single source of truth
 from ..objects.blake3_ref import (  # noqa: E402
     BLOCK_LEN,
@@ -381,8 +383,15 @@ def blake3_batch_hex(messages: list[bytes], max_chunks: int | None = None,
         need = max(1, max((len(m) + CHUNK_LEN - 1) // CHUNK_LEN for m in messages))
         max_chunks = 1 << (need - 1).bit_length()  # tier to a power of two
     B = len(messages)
-    padded = messages + [b""] * (_pad_to_tier(B) - B)
-    words, lengths = pack_messages(padded, max_chunks)
-    out = digests_to_hex(np.asarray(
-        blake3_batch(jnp.asarray(words), jnp.asarray(lengths), kernel=kernel)))
-    return out[:B]
+    # one span per stage of the call, under the caller's open span (the
+    # identify dispatch thread's pipeline.hash): host packing, the H2D
+    # enqueue, then the kernel through the digests' readback
+    trace = telemetry.current_trace()
+    with telemetry.span(trace, "hash.pack", files=B):
+        padded = messages + [b""] * (_pad_to_tier(B) - B)
+        words, lengths = pack_messages(padded, max_chunks)
+    with telemetry.span(trace, "hash.put"):
+        words, lengths = jnp.asarray(words), jnp.asarray(lengths)
+    with telemetry.span(trace, "hash.wait"):
+        digests = np.asarray(blake3_batch(words, lengths, kernel=kernel))
+    return digests_to_hex(digests)[:B]

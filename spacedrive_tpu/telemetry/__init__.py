@@ -10,9 +10,9 @@ per-job :class:`~.spans.Trace` trees, exposed three ways:
 Instrumented subsystems (the metric catalogue lives in
 docs/architecture/observability.md): job lifecycle (queue wait, step
 latency, lane occupancy), every pipeline stage (busy/blocked/idle),
-hasher dispatch (batches/files/bytes → live files-per-sec and MFU via
-ops/roofline.py), utils/retry.py (attempts, backoff, budget
-exhaustion), the fault seams, and sync ingest.
+hasher dispatch (batches/files/bytes → live files-per-sec),
+utils/retry.py (attempts, backoff, budget exhaustion), the fault seams,
+and sync ingest.
 
 ``SD_TELEMETRY=off`` turns every record call into a no-op (one global
 read); spans still *measure* so job-report stage timings never depend
@@ -41,15 +41,15 @@ from .registry import (
     reload_enabled,
     set_enabled,
 )
-from .spans import Span, Trace
+from .spans import Span, Trace, current_trace
 
 __all__ = [
     "DEFAULT_BUCKETS", "LOCK_BUCKETS", "METRIC_NAME_RE", "REQUEST_BUCKETS",
     "Registry",
     "Span", "Trace",
-    "add_event_hook", "counter", "enabled", "event", "finish_trace",
-    "gauge", "histogram", "job_trace", "recent_events", "registry",
-    "reload_enabled", "remove_event_hook", "render_prometheus", "reset",
+    "add_event_hook", "counter", "current_trace", "enabled", "event",
+    "finish_trace", "gauge", "histogram", "job_trace", "recent_events",
+    "registry", "reload_enabled", "remove_event_hook", "render_prometheus", "reset",
     "series_values", "set_enabled", "snapshot", "span", "start_trace",
     "value",
 ]
@@ -251,9 +251,6 @@ def reset() -> None:
 def _declare_core() -> None:
     gauge("sd_scan_files_per_sec",
           "files/s of the most recent completed identify pass")
-    gauge("sd_hash_mfu",
-          "u32-VPU model-op-utilization of the last hash batch "
-          "(ops/roofline.py model)")
     gauge("sd_hash_files_per_sec", "files/s of the last hash batch")
     gauge("sd_hash_bytes_per_sec", "payload bytes/s of the last hash batch")
     busy = counter("sd_pipeline_stage_busy_seconds",
@@ -335,8 +332,6 @@ def _declare_core() -> None:
     gauge("sd_hash_router_bytes_per_sec",
           "EWMA transfer-inclusive payload bytes/s per engine (router "
           "input)", labels=("backend",))
-    gauge("sd_hash_router_device_mfu",
-          "u32-VPU MFU implied by the router's device-engine EWMA rate")
     counter("sd_hash_router_flips_total",
             "engine flips by the per-batch hash router (hysteresis-damped)")
     counter("sd_hash_router_batches_total",

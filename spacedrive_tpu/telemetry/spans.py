@@ -19,6 +19,15 @@ Export: ``<data_dir>/logs/traces/<trace_id>.jsonl``, one span record per
 line (trace_id, span_id, parent_id, name, start_unix, duration_s,
 attrs). Completed traces also stay in a bounded in-process ring so
 ``telemetry.jobTrace`` serves them without touching disk.
+
+Profiler bridge: every thread-bound span also enters a
+``jax.profiler.TraceAnnotation`` named ``sd/<span name>`` for its
+duration, traced or not, so a profile shows the program's spans on the
+host plane, on the same clock as the device's ops (``sd/`` tells them
+apart from the runtime's own events). The annotation class is looked up
+only once JAX is already imported — this module never imports JAX, and a
+process that never does pays one dict lookup per span. Detached spans
+emit none: a profiler event must end on the thread that began it.
 """
 
 from __future__ import annotations
@@ -27,6 +36,7 @@ import itertools
 import json
 import logging
 import re
+import sys
 import threading
 import time
 from collections import OrderedDict
@@ -55,7 +65,30 @@ _CURRENT = threading.local()
 _ACTIVE_BY_THREAD: dict[int, tuple[str, str]] = {}
 
 
+#: host-plane namespace of the program's spans in a profiler trace
+PROFILER_PREFIX = "sd/"
+
+#: ``jax.profiler.TraceAnnotation`` once JAX has been imported (by anyone)
+_ANNOTATION: Any = None
+
+
+def _profiler_annotation(name: str) -> Any:
+    """A fresh ``TraceAnnotation`` for span ``name``, or None while JAX is
+    not loaded in this process."""
+    global _ANNOTATION
+    cls = _ANNOTATION
+    if cls is None:
+        cls = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+        if cls is None:
+            return None
+        _ANNOTATION = cls
+    return cls(PROFILER_PREFIX + name)
+
+
 def current_trace() -> "Trace | None":
+    """The trace the calling thread has an open span in — how code far
+    from the worker (a hasher, a detector) nests its spans under the
+    job's without a trace argument."""
     return getattr(_CURRENT, "trace", None)
 
 
@@ -72,7 +105,7 @@ class Span:
 
     __slots__ = ("name", "attrs", "trace", "span_id", "parent_id",
                  "start_unix", "duration_s", "error", "_t0", "_pinned",
-                 "_detached")
+                 "_detached", "_annotation")
 
     def __init__(self, name: str, trace: "Trace | None" = None,
                  attrs: dict[str, Any] | None = None,
@@ -100,6 +133,7 @@ class Span:
         # opener's chain. Children attach via an explicit ``parent=`` pin;
         # the detached span itself must pin its own parent (or root).
         self._detached = detached
+        self._annotation = None
         self._pinned = False
         if parent is not None and parent.span_id >= 0:
             self.parent_id = parent.span_id
@@ -120,12 +154,19 @@ class Span:
     def __enter__(self) -> "Span":
         if self.trace is not None:
             self.trace._enter(self)
+        if not self._detached:
+            self._annotation = _profiler_annotation(self.name)
+            if self._annotation is not None:
+                self._annotation.__enter__()
         self.start_unix = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, _exc, _tb) -> bool:
         self.duration_s = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         if exc_type is not None:
             self.error = True
         if self.trace is not None:

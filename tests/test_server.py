@@ -186,7 +186,7 @@ def _wait_scan_done(base, lib_id, min_paths=3, timeout=90):
         paths = _rspc(base, "search.paths", {}, lib_id)
         if len(paths.get("items", paths) if isinstance(paths, dict) else paths) >= min_paths:
             reports = _rspc(base, "jobs.reports", None, lib_id)
-            if reports and all(r.get("status") not in ("Running", "Queued")
+            if reports and all(r.get("status_name") not in ("Running", "Queued")
                                for r in _flatten_reports(reports)):
                 return
         time.sleep(0.5)
@@ -222,7 +222,10 @@ def test_scan_via_http_and_ranged_file(server_proc):
     assert len(locs) == 1
     loc_id = locs[0]["id"]
 
-    # drive a scan over HTTP (idempotent on top of the sd_init scan)
+    # drive a scan over HTTP (idempotent on top of the sd_init scan, once
+    # that scan is done: a rescan while its indexer still runs is refused
+    # as JobAlreadyRunning)
+    _wait_scan_done(base, lib_id)
     _rspc(base, "locations.fullRescan", {"location_id": loc_id}, lib_id)
     _wait_scan_done(base, lib_id)
 

@@ -1,14 +1,18 @@
 """Unified telemetry gate (ISSUE 5): registry semantics, the span tree a
 pipelined scan produces (and its reconciliation with the scan report's
-stage timings), the Prometheus text round-trip on GET /metrics, the
-SD_TELEMETRY=off no-op, and chaos-counter agreement with the fault
-suite's report metadata."""
+stage timings), the spans' bridge onto the profiler's host plane, the
+Prometheus text round-trip on GET /metrics, the SD_TELEMETRY=off no-op,
+and chaos-counter agreement with the fault suite's report metadata."""
 
 import json
+import os
 import random
 import re
+import subprocess
+import sys
 import threading
 import urllib.request
+from pathlib import Path
 
 import pytest
 
@@ -96,10 +100,12 @@ def test_concurrent_increments_from_threads():
 # -- the pipelined-scan span tree ---------------------------------------------
 
 
-@pytest.fixture(scope="module")
-def span_tree_scan(tmp_path_factory):
-    """One pipelined 2k-file identify; returns (tree, report metadata,
-    bytes hashed per the registry)."""
+@pytest.fixture(scope="module", params=[1, 4], ids=["shards1", "shards4"])
+def span_tree_scan(request, tmp_path_factory):
+    """One pipelined 2k-file identify, with the classic single-thread
+    prefetch (SD_SCAN_SHARDS=1) and the sharded one (4); returns (tree,
+    report metadata, bytes hashed per the registry, the JSONL export, the
+    scan-rate gauge, the shard count)."""
     telemetry.reset()
     telemetry.set_enabled(True)
     rng = random.Random(11)
@@ -116,10 +122,9 @@ def span_tree_scan(tmp_path_factory):
                 body = rng.randbytes(300 + (i * 13) % 1200)
             (p / f"f{i:03d}.dat").write_bytes(body)
 
-    import os
-
-    old_pipeline = os.environ.get("SD_PIPELINE")
+    old_env = {k: os.environ.get(k) for k in ("SD_PIPELINE", "SD_SCAN_SHARDS")}
     os.environ["SD_PIPELINE"] = "1"
+    os.environ["SD_SCAN_SHARDS"] = str(request.param)
     old_batch = fi.BATCH_SIZE
     fi.BATCH_SIZE = 256
     try:
@@ -135,11 +140,12 @@ def span_tree_scan(tmp_path_factory):
         node.shutdown()
     finally:
         fi.BATCH_SIZE = old_batch
-        if old_pipeline is None:
-            os.environ.pop("SD_PIPELINE", None)
-        else:
-            os.environ["SD_PIPELINE"] = old_pipeline
-    return tree, meta, hashed_bytes, trace_file, scan_rate
+        for key, old in old_env.items():
+            if old is None:
+                os.environ.pop(key, None)
+            else:
+                os.environ[key] = old
+    return tree, meta, hashed_bytes, trace_file, scan_rate, request.param
 
 
 def _spans_named(node, name, out=None):
@@ -152,7 +158,7 @@ def _spans_named(node, name, out=None):
 
 
 def test_span_tree_shape_and_stage_reconciliation(span_tree_scan):
-    tree, meta, _bytes, trace_file, _rate = span_tree_scan
+    tree, meta, _bytes, trace_file, _rate, shards = span_tree_scan
     assert tree["name"] == "job.file_identifier"
     batches = meta["pipeline_batches"]
     assert batches == 8  # ceil(2000/256)
@@ -178,10 +184,18 @@ def test_span_tree_shape_and_stage_reconciliation(span_tree_scan):
     assert {"pipeline.page", "pipeline.hash",
             "pipeline.commit"} <= run_children
 
-    # the gather rides INSIDE the page span (nesting, not just presence)
-    gathers = [c for p in pages for c in p["children"]
+    # the gather rides INSIDE the page span (nesting, not just presence):
+    # directly with one prefetch thread, under the page's per-slice
+    # pipeline.gather spans when the prefetch is sharded
+    if shards == 1:
+        holders = pages
+    else:
+        holders = [c for p in pages for c in p["children"]
+                   if c["name"] == "pipeline.gather"]
+        assert len(holders) >= batches
+    gathers = [c for h in holders for c in h["children"]
                if c["name"] == "identifier.gather"]
-    assert len(gathers) == batches
+    assert len(gathers) == len(holders)
 
     # reconciliation: report stage timings ARE the span sums (±5% per the
     # acceptance criterion; equality by construction here)
@@ -205,7 +219,7 @@ def test_span_tree_shape_and_stage_reconciliation(span_tree_scan):
 
 
 def test_span_attrs_sum_to_report_totals(span_tree_scan):
-    tree, meta, hashed_bytes, _tf, scan_rate = span_tree_scan
+    tree, meta, hashed_bytes, _tf, scan_rate, _shards = span_tree_scan
     gathers = _spans_named(tree, "identifier.gather")
     gathered_files = sum(g["attrs"]["files"] for g in gathers)
     gathered_bytes = sum(g["attrs"]["bytes"] for g in gathers)
@@ -215,6 +229,128 @@ def test_span_attrs_sum_to_report_totals(span_tree_scan):
     # every gathered byte was hashed exactly once on the cpu backend
     assert gathered_bytes == hashed_bytes
     assert scan_rate > 0
+
+
+# -- spans on the profiler's host plane ----------------------------------------
+
+
+def _host_events(logdir: Path) -> dict[tuple[int, str], list[tuple[int, int]]]:
+    """(line index, name) → [(start_ns, end_ns)] of the ``sd/`` events on
+    the host planes of the profile under ``logdir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = logdir.glob("**/*.xplane.pb")
+    out: dict = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for idx, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith("sd/"):
+                    out.setdefault((idx, ev.name), []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    return out
+
+
+def test_spans_show_on_the_profiler_host_plane(tmp_path):
+    """A thread-bound span is an ``sd/<name>`` event on its thread's line,
+    traced or bare, nested by time inside its parent; a detached span
+    (entered on one thread, exited on another) emits nothing."""
+    import jax
+
+    trace = telemetry.start_trace("job.t")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with telemetry.span(trace, "t.outer"):
+            with telemetry.span(None, "t.bare"):
+                pass
+        detached = telemetry.span(trace, "t.detached", detached=True)
+        detached.__enter__()
+        closer = threading.Thread(
+            target=lambda: detached.__exit__(None, None, None))
+        closer.start()
+        closer.join()
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(tmp_path)
+    lines = {name: idx for idx, name in events}
+    assert lines["sd/t.outer"] == lines["sd/t.bare"]
+    ((o0, o1),) = events[(lines["sd/t.outer"], "sd/t.outer")]
+    ((b0, b1),) = events[(lines["sd/t.bare"], "sd/t.bare")]
+    assert o0 <= b0 <= b1 <= o1
+    assert "sd/t.detached" not in lines
+    # the trace records all three, the detached one included
+    assert {"t.outer", "t.detached"} <= set(trace.totals())
+
+
+def test_telemetry_import_loads_no_jax():
+    """The bridge resolves the profiler lazily: importing telemetry in a
+    fresh process leaves JAX unloaded."""
+    root = Path(__file__).resolve().parents[1]
+    code = ("import sys, spacedrive_tpu.telemetry as t\n"
+            "with t.span(None, 'x'):\n"
+            "    pass\n"
+            "print(sorted(m for m in sys.modules if m.split('.')[0] == 'jax'))")
+    out = subprocess.run([sys.executable, "-c", code], cwd=root,
+                         capture_output=True, text=True, timeout=120,
+                         env={**os.environ, "PYTHONPATH": str(root)})
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "[]"
+
+
+#: span name → the job whose trace holds it, for the chain below
+CHAIN_SPANS = {
+    "indexer": ("indexer.walk", "indexer.rows", "indexer.save"),
+    "file_identifier": ("identifier.select", "identifier.gather",
+                        "hash.pack", "hash.put", "hash.wait"),
+    "dedup_detector": ("dedup.select", "dedup.gather", "dedup.minhash",
+                       "dedup.sweep", "dedup.verify", "dedup.persist"),
+}
+
+
+def test_scan_chain_records_every_span(tmp_path, tmp_data_dir, monkeypatch):
+    """indexer → identifier (device hasher, on the CPU here) → dedup: each
+    job's trace totals carry its per-batch spans, and the children sum to
+    no more than the span they nest in."""
+    from spacedrive_tpu.locations import create_location, scan_location
+    from spacedrive_tpu.node import Node
+
+    monkeypatch.setenv("SD_PIPELINE", "1")
+    rng = random.Random(23)
+    tree = tmp_path / "tree"
+    tree.mkdir()
+    base = bytearray(rng.randbytes(160_000))
+    for i in range(4):
+        (tree / f"f{i}.dat").write_bytes(rng.randbytes(150_000 + i))
+    (tree / "a.dat").write_bytes(base)
+    base[1000] ^= 0xFF
+    (tree / "a_copy.dat").write_bytes(base)
+
+    node = Node(tmp_data_dir, probe_accelerator=False, watch_locations=False)
+    try:
+        lib = node.libraries.create("chain-spans")
+        loc = create_location(lib, str(tree), hasher="tpu")
+        scan_location(lib, loc["id"])
+        assert node.jobs.wait_idle(300)
+        rows = lib.db.query("SELECT name, metadata FROM job")
+    finally:
+        node.shutdown()
+    spans = {r["name"]: _decoded(r["metadata"])["trace"]["spans"]
+             for r in rows if r["name"] in CHAIN_SPANS}
+    assert set(spans) == set(CHAIN_SPANS)
+    for job, names in CHAIN_SPANS.items():
+        for name in names:
+            assert spans[job].get(name, {}).get("count", 0) > 0, (job, name)
+
+    def total(job, name):
+        return spans[job][name]["total_s"]
+
+    ident = sum(total("file_identifier", f"hash.{p}")
+                for p in ("pack", "put", "wait"))
+    assert ident <= total("file_identifier", "pipeline.hash")
+    dedup = sum(total("dedup_detector", n)
+                for n in CHAIN_SPANS["dedup_detector"])
+    assert dedup <= total("dedup_detector", "job.step")
 
 
 def test_trace_resume_continues_open_trace():
@@ -278,8 +414,10 @@ def test_metrics_endpoint_prometheus_roundtrip(tmp_data_dir):
     # the acceptance vocabulary is served
     for required in ("sd_scan_files_per_sec", "sd_pipeline_stage_busy_seconds",
                      "sd_retry_attempts_total", "sd_faults_fired_total",
-                     "sd_hash_mfu"):
+                     "sd_hash_batches_total"):
         assert required in families, required
+    # the roofline-model gauges nothing read are gone
+    assert not [f for f in families if "mfu" in f]
 
     # round-trip: scraped values equal registry values
     assert 'sd_t_http_total{route="/spacedrive"} 3' in body
