@@ -880,25 +880,11 @@ class ShardedHasher(TpuHasher):
                                               jnp.asarray(lengths))
 
     def _hash_bucket(self, msgs: list[bytes], cap: int) -> list[str]:
-        import jax.numpy as jnp
-        import numpy as np
+        from ..ops.blake3_jax import _pad_to_tier, hash_rows_hex
+        from ..parallel.mesh import pad_batch_for_mesh, sharded_row_hasher
 
-        from ..ops.blake3_jax import _pad_to_tier, digests_to_hex, pack_messages
-        from ..parallel.mesh import pad_batch_for_mesh, sharded_hasher
-
-        B = len(msgs)
-        target = pad_batch_for_mesh(_pad_to_tier(B), self._mesh)
-        fn = sharded_hasher(self._mesh)
-        # the same hash.pack / hash.put / hash.wait spans as
-        # blake3_batch_hex, the single-device form of this call
-        trace = telemetry.current_trace()
-        with telemetry.span(trace, "hash.pack", files=B):
-            words, lengths = pack_messages(msgs + [b""] * (target - B), cap)
-        with telemetry.span(trace, "hash.put"):
-            words, lengths = jnp.asarray(words), jnp.asarray(lengths)
-        with telemetry.span(trace, "hash.wait"):
-            digests = np.asarray(fn(words, lengths))
-        return digests_to_hex(digests)[:B]
+        lanes = pad_batch_for_mesh(_pad_to_tier(len(msgs)), self._mesh)
+        return hash_rows_hex(msgs, cap, lanes, sharded_row_hasher(self._mesh))
 
 
 _BACKENDS: dict[str, Callable[[], HasherBackend]] = {

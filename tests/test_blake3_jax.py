@@ -54,6 +54,44 @@ def test_cas_ids_match_cpu_path(rng):
     assert got == want
 
 
+@pytest.mark.parametrize("cap,n,kernel", [
+    (1, 9, None),
+    (4, 3, None),
+    (4, 3, "xla"),
+    (64, 3, None),
+    (101, 9, None),
+])
+def test_batch_hex_parity_per_bucket(rng, cap, n, kernel):
+    """Every bucket cap the hasher uses, at lane counts that are not tiers
+    (so padding lanes are hashed and cut off); cap 64 carries the sampled
+    57,352-byte message the photo scan hashes."""
+    if cap == 64:
+        lens = [SAMPLED_MESSAGE_LEN] * n
+    else:
+        lens = [rng.randrange(0, cap * 1024 + 1) for _ in range(n - 1)]
+        lens.append(cap * 1024)  # a message that fills its bucket
+    msgs = [rng.randbytes(k) for k in lens]
+    got = blake3_jax.blake3_batch_hex(msgs, max_chunks=cap, kernel=kernel)
+    assert got == [blake3(m).hex() for m in msgs]
+
+
+def test_batch_hex_packs_rows_without_host_transpose(rng, monkeypatch):
+    """The one-shot path packs row-major and permutes on the device: the
+    batch-minor host packer is never called."""
+    def no_host_transpose(*args, **kwargs):
+        raise AssertionError("blake3_batch_hex called pack_messages")
+
+    monkeypatch.setattr(blake3_jax, "pack_messages", no_host_transpose)
+    msgs = [rng.randbytes(SAMPLED_MESSAGE_LEN) for _ in range(3)] + [b""]
+    got = blake3_jax.blake3_batch_hex(msgs, max_chunks=64)
+    assert got == [blake3(m).hex() for m in msgs]
+    # the next call packs into the same host buffer: shorter messages must
+    # not hash the previous call's bytes behind them
+    short = [rng.randbytes(n) for n in (1, 1000, 30_000)]
+    assert blake3_jax.blake3_batch_hex(short, max_chunks=64) == \
+        [blake3(m).hex() for m in short]
+
+
 def test_pack_messages_layout():
     msgs = [b"\x01\x02\x03\x04" + b"\x00" * 60, b"\xff" * 8]
     words, lengths = blake3_jax.pack_messages(msgs, 1)
@@ -64,6 +102,31 @@ def test_pack_messages_layout():
     assert words[0, 0, 0, 1] == 0xFFFFFFFF
     with pytest.raises(ValueError):
         blake3_jax.pack_messages([b"x" * 2000], 1)
+
+
+def test_pack_rows_layout():
+    msgs = [bytes(range(1, 9)), b"\xff" * 1030, b""]
+    rows, lengths = blake3_jax.pack_rows(msgs, 2)
+    assert rows.shape == (3, 2 * 256) and rows.dtype == np.uint32
+    assert rows.flags.c_contiguous
+    assert list(lengths) == [8, 1030, 0]
+    # row i holds message i's bytes in order, little-endian words
+    assert rows[0, 0] == 0x04030201 and rows[0, 1] == 0x08070605
+    for msg, row in zip(msgs, rows):
+        raw = row.tobytes()
+        assert raw[: len(msg)] == msg
+        assert not any(raw[len(msg):])  # zero tail
+    # the batch-minor packer's words, permuted back, are the same rows
+    words, words_lengths = blake3_jax.pack_messages(msgs, 2)
+    np.testing.assert_array_equal(words.transpose(3, 2, 0, 1).reshape(3, -1), rows)
+    np.testing.assert_array_equal(words_lengths, lengths)
+    # packing into a used buffer overwrites every byte: no stale tail
+    out = np.full(4 * 2 * 1024, 0xAB, np.uint8)
+    reused, _ = blake3_jax.pack_rows(msgs, 2, out=out)
+    np.testing.assert_array_equal(reused, rows)
+    assert np.shares_memory(reused, out)
+    with pytest.raises(ValueError):
+        blake3_jax.pack_rows([b"x" * 2049], 2)
 
 
 def test_hybrid_hasher_adaptive_routing(tmp_path):
