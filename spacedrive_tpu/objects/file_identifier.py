@@ -631,8 +631,30 @@ class FileIdentifierJob(StatefulJob):
             elapsed = trace.elapsed_s()
             if elapsed > 0:
                 _SCAN_RATE.set(round(total / elapsed, 1))
+        if trace is not None:
+            buckets = _bucket_tallies(trace)
+            if buckets:
+                run_metadata["hash_buckets"] = buckets
         logger.info("file_identifier finished: %s", run_metadata)
         return run_metadata
+
+
+def _bucket_tallies(trace) -> dict[str, dict[str, int]]:
+    """``{cap: {calls, files, lanes, payload_bytes}}`` summed over this
+    job's ``hash.bucket`` spans: its own device bucket calls, apart from
+    what other jobs and the H_HASH service add to the process-wide
+    ``sd_hash_bucket_*`` counters."""
+    out: dict[str, dict[str, int]] = {}
+    for rec in trace.records():
+        if rec["name"] != "hash.bucket":
+            continue
+        attrs = rec.get("attrs") or {}
+        tally = out.setdefault(str(attrs["cap"]), dict.fromkeys(
+            ("calls", "files", "lanes", "payload_bytes"), 0))
+        tally["calls"] += 1
+        for key in ("files", "lanes", "payload_bytes"):
+            tally[key] += attrs[key]
+    return out
 
 
 def _abs_path(location_path: str, row: dict) -> str:

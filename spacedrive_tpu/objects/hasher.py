@@ -41,6 +41,12 @@ logger = logging.getLogger(__name__)
 #: largest whole-file message (100KiB + 8B size prefix)
 SMALL_BUCKETS = (1, 4, 16, 32, 64, 101)
 SAMPLED_CHUNKS = (SAMPLED_MESSAGE_LEN + 1023) // 1024  # 57
+#: every device call of a bucket hashes a fixed number of rows: at most
+#: BUCKET_MAX_ROWS, and at most BUCKET_CALL_CHUNKS chunks (32 MiB) of rows a
+#: call. One shape per bucket, whatever the page size or the calls before
+#: it; a bucket with more messages takes several calls (``bucket_rows``)
+BUCKET_MAX_ROWS = 1024
+BUCKET_CALL_CHUNKS = 32 * 1024
 
 
 # -- dispatch telemetry --------------------------------------------------------
@@ -83,6 +89,24 @@ _ROUTER_BATCHES = telemetry.counter(
     "sd_hash_router_batches_total",
     "hash (sub-)batches the hybrid router dispatched per engine",
     labels=("backend",))
+
+# -- small-file bucket telemetry -----------------------------------------------
+# Per chunk-capacity bucket of the whole-file path (``_bucketed_hash``):
+# device calls, messages, rows (the empty lanes included) and payload bytes,
+# so the rows' padding share is lanes x cap x 1024 against payload.
+_BUCKET_CALLS = telemetry.counter(
+    "sd_hash_bucket_calls_total",
+    "device hash calls per chunk-capacity bucket", labels=("cap",))
+_BUCKET_FILES = telemetry.counter(
+    "sd_hash_bucket_files_total",
+    "cas messages hashed per chunk-capacity bucket", labels=("cap",))
+_BUCKET_LANES = telemetry.counter(
+    "sd_hash_bucket_lanes_total",
+    "rows hashed per chunk-capacity bucket, padding lanes included",
+    labels=("cap",))
+_BUCKET_PAYLOAD = telemetry.counter(
+    "sd_hash_bucket_payload_bytes_total",
+    "cas-message payload bytes per chunk-capacity bucket", labels=("cap",))
 
 class _OutermostGuard:
     """Process-wide outermost-call tracker (not thread-local: the
@@ -255,7 +279,8 @@ class TpuHasher:
         try:
             from ..native import cas_native
         except Exception:
-            self._hash_python(paths, sizes, indices, out)
+            # no native toolchain: the Python gather into the buckets
+            self._hash_small(paths, sizes, indices, out)
             return
 
         import numpy as np
@@ -324,23 +349,6 @@ class TpuHasher:
         for j, cid in zip(ok, ids):
             out[indices[j]] = cid
 
-    def _hash_python(self, paths, sizes, indices: list[int], out: list) -> None:
-        """No native toolchain: pure-Python gather into the bucketed kernel."""
-        messages = read_sampled_batch([paths[i] for i in indices],
-                                      [sizes[i] for i in indices])
-        ok = [j for j, m in enumerate(messages) if not isinstance(m, Exception)]
-        for j, m in enumerate(messages):
-            if isinstance(m, Exception):
-                out[indices[j]] = m
-        hexes = self._hash_bucket([messages[j] for j in ok], SAMPLED_CHUNKS)
-        for j, h in zip(ok, hexes):
-            out[indices[j]] = h[:16]
-
-    def _hash_bucket(self, msgs: list[bytes], cap: int) -> list[str]:
-        from ..ops.blake3_jax import blake3_batch_hex
-
-        return blake3_batch_hex(msgs, max_chunks=cap)
-
     @_count_hash_gathered
     def hash_gathered(self,
                       messages: list[bytes | Exception]) -> list[str | Exception]:
@@ -351,6 +359,12 @@ class TpuHasher:
             messages, lambda msgs: _bucketed_hash(msgs, self._hash_bucket))
 
     # hooks the sharded variant overrides
+    def _hash_bucket(self, msgs: list[bytes], cap: int,
+                     lanes: int) -> list[str]:
+        from ..ops.blake3_jax import blake3_batch_hex
+
+        return blake3_batch_hex(msgs, max_chunks=cap, lanes=lanes)
+
     def _pad_lanes(self, n: int) -> int:
         return n
 
@@ -879,12 +893,13 @@ class ShardedHasher(TpuHasher):
         return sharded_row_hasher(self._mesh)(jnp.asarray(rows32),
                                               jnp.asarray(lengths))
 
-    def _hash_bucket(self, msgs: list[bytes], cap: int) -> list[str]:
-        from ..ops.blake3_jax import _pad_to_tier, hash_rows_hex
-        from ..parallel.mesh import pad_batch_for_mesh, sharded_row_hasher
+    def _hash_bucket(self, msgs: list[bytes], cap: int,
+                     lanes: int) -> list[str]:
+        from ..ops.blake3_jax import hash_rows_hex
+        from ..parallel.mesh import sharded_row_hasher
 
-        lanes = pad_batch_for_mesh(_pad_to_tier(len(msgs)), self._mesh)
-        return hash_rows_hex(msgs, cap, lanes, sharded_row_hasher(self._mesh))
+        return hash_rows_hex(msgs, cap, self._pad_lanes(lanes),
+                             sharded_row_hasher(self._mesh))
 
 
 _BACKENDS: dict[str, Callable[[], HasherBackend]] = {
@@ -963,21 +978,50 @@ def _hash_gathered_messages(messages: list[bytes | Exception],
     return out
 
 
+def bucket_rows(cap: int) -> int:
+    """Rows of every device call of the ``cap``-chunk bucket: the most, up
+    to BUCKET_MAX_ROWS and by halves, whose rows hold at most
+    BUCKET_CALL_CHUNKS chunks (1,024 rows for caps 1 to 32, 512 for 64,
+    256 for 101)."""
+    rows = BUCKET_MAX_ROWS
+    while rows > 1 and rows * cap > BUCKET_CALL_CHUNKS:
+        rows //= 2
+    return rows
+
+
 def _bucketed_hash(messages: list[bytes], hash_bucket) -> list[str]:
     """Bucket variable-size cas messages by chunk count and hash each
-    bucket through ``hash_bucket(msgs, cap)``; returns 16-hex cas_ids in
-    input order. The one bucketing scheme shared by the local small-file
-    path and the H_HASH service."""
+    bucket through ``hash_bucket(msgs, cap, lanes)``, in calls of
+    ``bucket_rows(cap)`` rows (a bucket with more messages takes several
+    calls); returns 16-hex cas_ids in input order. The one bucketing
+    scheme shared by the local small-file path and the H_HASH service.
+    Each call is a ``hash.bucket`` span under the calling
+    thread's trace, and is counted by cap (the ``sd_hash_bucket_*``
+    family) once it returns."""
     out: list[str | None] = [None] * len(messages)
     buckets: dict[int, list[int]] = {}
     for j, msg in enumerate(messages):
         chunks = max(1, (len(msg) + 1023) // 1024)
         cap = next((b for b in SMALL_BUCKETS if b >= chunks), chunks)
         buckets.setdefault(cap, []).append(j)
-    for cap, js in sorted(buckets.items()):
-        hexes = hash_bucket([messages[j] for j in js], cap)
-        for j, h in zip(js, hexes):
-            out[j] = h[:16]
+    trace = telemetry.current_trace()
+    for cap, bucket in sorted(buckets.items()):
+        lanes = bucket_rows(cap)
+        label = str(cap)
+        for at in range(0, len(bucket), lanes):
+            js = bucket[at : at + lanes]
+            msgs = [messages[j] for j in js]
+            payload = sum(map(len, msgs))
+            with telemetry.span(trace, "hash.bucket", cap=cap,
+                                files=len(msgs), lanes=lanes,
+                                payload_bytes=payload):
+                hexes = hash_bucket(msgs, cap, lanes)
+            _BUCKET_CALLS.inc(cap=label)
+            _BUCKET_FILES.inc(len(msgs), cap=label)
+            _BUCKET_LANES.inc(lanes, cap=label)
+            _BUCKET_PAYLOAD.inc(payload, cap=label)
+            for j, h in zip(js, hexes):
+                out[j] = h[:16]
     return out  # type: ignore[return-value]
 
 
@@ -986,10 +1030,7 @@ def hash_messages(messages: list[bytes]) -> list[str]:
     shared-hasher service (H_HASH): device-bucketed when an accelerator is
     present, else native C++ BLAKE3, else the Python oracle."""
     if _accelerator_available():
-        from ..ops.blake3_jax import blake3_batch_hex
-
-        return _bucketed_hash(
-            messages, lambda msgs, cap: blake3_batch_hex(msgs, max_chunks=cap))
+        return _bucketed_hash(messages, TpuHasher()._hash_bucket)
     try:
         from ..native import cas_native
 

@@ -456,15 +456,18 @@ def hash_rows_hex(messages: list[bytes], max_chunks: int, lanes: int,
 
 
 def blake3_batch_hex(messages: list[bytes], max_chunks: int | None = None,
-                     kernel: str | None = None) -> list[str]:
+                     kernel: str | None = None,
+                     lanes: int | None = None) -> list[str]:
     """Convenience one-shot: pack rows → device hash → hex digests. Pads the
-    batch to a size tier (empty-message lanes) to bound compiled-shape count."""
+    batch to ``lanes`` rows (empty-message lanes), by default to a size tier,
+    to bound compiled-shape count."""
     if not messages:
         return []
     if max_chunks is None:
         need = max(1, max((len(m) + CHUNK_LEN - 1) // CHUNK_LEN for m in messages))
         max_chunks = 1 << (need - 1).bit_length()  # tier to a power of two
     # each call puts its rows anew, so the device copy may be donated
-    return hash_rows_hex(messages, max_chunks, _pad_to_tier(len(messages)),
+    return hash_rows_hex(messages, max_chunks,
+                         lanes or _pad_to_tier(len(messages)),
                          functools.partial(blake3_batch_rows, kernel=kernel,
                                            donate=True))

@@ -51,13 +51,13 @@ def one_chip():
         compilation_cache.reset_cache()
 
 
-def _blake3_rows(kernel):
+def _blake3_rows(kernel, lanes=4096, chunks=64):
     from spacedrive_tpu.ops.blake3_jax import _blake3_batch_rows_impl
 
     def build(spec):
-        # the hasher's largest device tier: 4096 lanes x 64 chunks
         return (lambda r, n: _blake3_batch_rows_impl(r, n, kernel=kernel),
-                [spec((4096, 64 * 256), "uint32"), spec((4096,), "int32")])
+                [spec((lanes, chunks * 256), "uint32"),
+                 spec((lanes,), "int32")])
     return build
 
 
@@ -111,8 +111,12 @@ def _search(kind):
 
 
 CASES = {
+    # the hasher's largest device tier: 4096 lanes x 64 chunks
     "blake3_rows_xla": _blake3_rows("xla"),
     "blake3_rows_pallas": _blake3_rows("pallas"),
+    # the whole-file bucket of 101 chunks (100 KiB + the size word), the
+    # one chunk count not a power of two, at its 256 rows a call
+    "blake3_rows_xla_cap101": _blake3_rows("xla", 256, 101),
     "minhash_rows": _minhash,
     "similar_pairs_count": _similar_pairs,
     "resize_batch": _resize,

@@ -4,15 +4,28 @@
 gathered: sampled 57,352-byte messages of large files, whole-file messages
 of small ones, and gather failures, which pass through in place. The
 single-device and the mesh-sharded hasher must give the CPU path's cas_ids
-position by position (the sharded one on the suite's 8 virtual devices).
+position by position (the sharded one on the suite's 8 virtual devices),
+and the program's reference BLAKE3's.
+
+The bucket-edge page holds the cas message of a file at each edge of a
+chunk-capacity bucket (size + 8 bytes fills 1, 4, 16, 32, 64 or 101
+chunks of 1,024 bytes) and of one byte more, which takes the next bucket,
+and one sampled file: one call hashes every bucket.
 """
 
 import random
 
 import pytest
 
-from spacedrive_tpu.objects.cas import SAMPLED_MESSAGE_LEN
+from spacedrive_tpu.objects.blake3_ref import blake3
+from spacedrive_tpu.objects.cas import (SAMPLED_MESSAGE_LEN,
+                                        cas_message_from_bytes)
 from spacedrive_tpu.objects.hasher import CpuHasher, ShardedHasher, TpuHasher
+
+#: file sizes at each bucket edge and one byte past it, up to the
+#: whole-file limit (100 KiB)
+EDGE_SIZES = (1016, 1017, 4088, 4089, 16376, 16377, 32760, 32761,
+              65528, 65529, 102400)
 
 
 def _gathered_messages() -> list:
@@ -24,10 +37,21 @@ def _gathered_messages() -> list:
     return msgs
 
 
-@pytest.mark.parametrize("backend", [TpuHasher, ShardedHasher],
-                         ids=["tpu", "tpu-sharded"])
-def test_hash_gathered_matches_cpu_hasher(backend):
-    msgs = _gathered_messages()
+def _bucket_edge_messages() -> list:
+    rng = random.Random(25)
+    msgs: list = [cas_message_from_bytes(rng.randbytes(n))
+                  for n in EDGE_SIZES + (250_000,)]
+    msgs.append(OSError("gather failed: c.dll"))
+    rng.shuffle(msgs)
+    return msgs
+
+
+@pytest.mark.parametrize("backend,messages", [
+    (TpuHasher, _gathered_messages), (ShardedHasher, _gathered_messages),
+    (TpuHasher, _bucket_edge_messages), (ShardedHasher, _bucket_edge_messages),
+], ids=["tpu", "tpu-sharded", "tpu-bucket_edges", "tpu-sharded-bucket_edges"])
+def test_hash_gathered_matches_cpu_hasher(backend, messages):
+    msgs = messages()
     want = CpuHasher().hash_gathered(msgs)
     got = backend().hash_gathered(msgs)
     assert len(got) == len(msgs)
@@ -35,4 +59,49 @@ def test_hash_gathered_matches_cpu_hasher(backend):
         if isinstance(msg, Exception):
             assert g is msg and w is msg
         else:
-            assert g == w and len(g) == 16
+            assert g == w == blake3(msg).hex()[:16]
+
+
+def test_bucket_edge_page_hashes_every_bucket():
+    """One call over the bucket-edge page makes one device call per
+    bucket: a message that fills a bucket stays in it, one byte more takes
+    the next, and the sampled message takes the 64-chunk bucket."""
+    hasher = TpuHasher()
+    calls = []
+    # the digests are the parametrised test's; here only the routing
+    hasher._hash_bucket = lambda msgs, cap, lanes: (
+        calls.append((cap, sorted(len(m) - 8 for m in msgs)))
+        or ["0" * 64] * len(msgs))
+    hasher.hash_gathered(_bucket_edge_messages())
+    assert calls == [(1, [1016]), (4, [1017, 4088]), (16, [4089, 16376]),
+                     (32, [16377, 32760]), (64, [32761, 57344, 65528]),
+                     (101, [65529, 102400])]
+
+
+def test_bucket_calls_do_not_depend_on_earlier_calls():
+    """Every call of a bucket hashes ``bucket_rows(cap)`` rows, whatever
+    came before: a tiny first call does not shrink the calls of a 4,096-file
+    page, a bucket with more messages is split, and no page after the
+    first compiles a new shape."""
+    from spacedrive_tpu.objects.hasher import SMALL_BUCKETS, bucket_rows
+    from spacedrive_tpu.ops.blake3_jax import _blake3_batch_rows_impl
+
+    assert [bucket_rows(cap) for cap in SMALL_BUCKETS] == [
+        1024, 1024, 1024, 1024, 512, 256]
+    rng = random.Random(27)
+    hasher = TpuHasher()
+
+    def page(n):
+        msgs = [rng.randbytes(600) for _ in range(n)]
+        assert hasher.hash_gathered(msgs) == CpuHasher().hash_gathered(msgs)
+
+    page(3)  # cap 1: three messages
+    compiled = _blake3_batch_rows_impl._cache_size()
+    calls = []
+    real = hasher._hash_bucket
+    hasher._hash_bucket = lambda msgs, cap, lanes: (
+        calls.append((cap, len(msgs), lanes)) or real(msgs, cap, lanes))
+    page(4096)
+    page(1500)
+    assert calls == [(1, 1024, 1024)] * 4 + [(1, 1024, 1024), (1, 476, 1024)]
+    assert _blake3_batch_rows_impl._cache_size() == compiled

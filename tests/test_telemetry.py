@@ -353,6 +353,83 @@ def test_scan_chain_records_every_span(tmp_path, tmp_data_dir, monkeypatch):
     assert dedup <= total("dedup_detector", "job.step")
 
 
+def _bucket_sums(spans) -> dict:
+    out: dict = {}
+    for s in spans:
+        a = s["attrs"]
+        t = out.setdefault(str(a["cap"]), {"calls": 0, "files": 0, "lanes": 0,
+                                           "payload_bytes": 0})
+        t["calls"] += 1
+        for key in ("files", "lanes", "payload_bytes"):
+            t[key] += a[key]
+    return out
+
+
+def test_bucket_page_records_a_span_per_bucket():
+    """One gathered page through the device hasher (on the CPU here): one
+    ``hash.bucket`` span per bucket it uses, holding that bucket's
+    ``hash.pack``/``put``/``wait``, with files, the bucket's fixed lanes and
+    payload bytes that add up to the page and to the by-cap counters."""
+    from spacedrive_tpu.objects.hasher import TpuHasher, bucket_rows
+
+    rng = random.Random(25)
+    sizes = [300] * 9 + [3000] * 3 + [20_000] * 2 + [90_000] + [57_352] * 4
+    msgs = [rng.randbytes(n) for n in sizes]
+    trace = telemetry.start_trace("job.t")
+    with telemetry.span(trace, "pipeline.hash"):
+        TpuHasher().hash_gathered(msgs)
+    tree = trace.tree()
+    spans = _spans_named(tree, "hash.bucket")
+    sums = _bucket_sums(spans)
+    assert sums == {
+        "1": {"calls": 1, "files": 9, "lanes": 1024, "payload_bytes": 2700},
+        "4": {"calls": 1, "files": 3, "lanes": 1024, "payload_bytes": 9000},
+        "32": {"calls": 1, "files": 2, "lanes": 1024,
+               "payload_bytes": 40_000},
+        "64": {"calls": 1, "files": 4, "lanes": 512,
+               "payload_bytes": 229_408},
+        "101": {"calls": 1, "files": 1, "lanes": 256,
+                "payload_bytes": 90_000},
+    }
+    for s in spans:
+        assert s["attrs"]["lanes"] == bucket_rows(s["attrs"]["cap"])
+        assert sorted(c["name"] for c in s["children"]) == [
+            "hash.pack", "hash.put", "hash.wait"]
+    for cap, t in sums.items():
+        assert telemetry.value("sd_hash_bucket_calls_total", cap=cap) == 1
+        for key in ("files", "lanes", "payload_bytes"):
+            assert telemetry.value(f"sd_hash_bucket_{key}_total",
+                                   cap=cap) == t[key]
+
+
+def test_identifier_hash_buckets_sum_its_spans(tmp_path, monkeypatch):
+    """The ``file_identifier`` report's ``hash_buckets`` are its own
+    ``hash.bucket`` spans summed by cap, page after page."""
+    monkeypatch.setattr(fi, "BATCH_SIZE", 16)
+    monkeypatch.setenv("SD_PIPELINE", "1")
+    rng = random.Random(26)
+    tree = tmp_path / "tree"
+    tree.mkdir()
+    for i, n in enumerate([500] * 20 + [5000] * 10 + [70_000] * 4
+                          + [150_000] * 6):
+        (tree / f"f{i:02d}.dat").write_bytes(rng.randbytes(n))
+    node, lib, loc_id = _seed_library(tmp_path / "data", tree, "buckets")
+    try:
+        from spacedrive_tpu.models import Location
+
+        lib.db.update(Location, {"id": loc_id}, {"hasher": "tpu"})
+        jid = _identify(node, lib, loc_id)
+        meta = _decoded(lib.db.find_one(JobRow, {"id": jid})["metadata"])
+        tree_ = node.router.resolve("telemetry.jobTrace", jid)
+    finally:
+        node.shutdown()
+    sums = _bucket_sums(_spans_named(tree_, "hash.bucket"))
+    assert meta["hash_buckets"] == sums
+    assert set(sums) == {"1", "16", "101", "64"}
+    assert sum(t["files"] for t in sums.values()) == 40
+    assert sum(t["calls"] for t in sums.values()) > 4  # pages of 16
+
+
 def test_trace_resume_continues_open_trace():
     """The worker's pause path leaves the trace OPEN; a resume under the
     same id continues it (span sums keep reconciling with accumulated
