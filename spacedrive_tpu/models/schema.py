@@ -458,6 +458,25 @@ class ChunkManifest(Model):
     INDEXES = (("chunk_hash",),)
 
 
+class ObjectMinhash(Model):
+    """MinHash signature (ops/minhash.py) of a sampled object's cas
+    message: K little-endian uint32. The identify page computes it on the
+    device from the rows it hashed, and the identify commit writes it; the
+    dedup detector reads it instead of gathering the file again. Keyed by
+    object: equal cas_ids are equal cas messages, so equal signatures.
+    Derived, local-only data like NearDuplicate: not synced, written as
+    objects are identified, rows cascade away with their objects. An object
+    with no row (hashed on the CPU, synced in, or identified before the
+    table existed) takes the dedup detector's own gather."""
+
+    TABLE = "object_minhash"
+    FIELDS = {
+        "object_id": Field(_I, primary_key=True,
+                           references="object.id", on_delete="CASCADE"),
+        "signature": Field("BLOB", nullable=False),
+    }
+
+
 ALL_MODELS: tuple[type[Model], ...] = (
     Instance,  # referenced by op-log tables, create first
     SharedOperationRow,
@@ -484,6 +503,7 @@ ALL_MODELS: tuple[type[Model], ...] = (
     Notification,
     NearDuplicate,
     ChunkManifest,
+    ObjectMinhash,
 )
 
 SYNCED_MODELS: dict[str, type[Model]] = {

@@ -3,9 +3,12 @@
 The reference collapses only EXACT duplicates (same cas_id → one Object).
 This module finds *near* duplicates — edited photos, re-encoded media,
 truncated copies — by running the TPU MinHash pipeline (ops/minhash.py) over
-a location's sampled content: native gather reads each file's cas sample
-rows (the same bytes the identifier hashed), the device computes signatures,
-and the all-pairs sweep returns similarity groups.
+a location's sampled content, and the all-pairs sweep returns similarity
+groups. Each file's signature is its object's ``object_minhash`` row, which
+the identify page computed on the device from the rows it hashed; a file
+with none (hashed on the CPU, synced in, identified before the table) is
+gathered here — native gather reads its cas sample rows, the same bytes the
+identifier hashed — and the device computes its signature.
 """
 
 from __future__ import annotations
@@ -18,14 +21,15 @@ import numpy as np
 from .. import telemetry
 from ..jobs import EarlyFinish, StatefulJob, StepResult, WorkerContext
 from ..models import FilePath
-from .cas import MINIMUM_FILE_SIZE, SAMPLED_MESSAGE_LEN
+from .cas import MINIMUM_FILE_SIZE
+from .hasher import SAMPLED_CHUNKS
 
 if TYPE_CHECKING:
     from ..library import Library
 
 logger = logging.getLogger(__name__)
 
-SAMPLED_STRIDE = ((SAMPLED_MESSAGE_LEN + 1023) // 1024) * 1024  # 58368
+SAMPLED_STRIDE = SAMPLED_CHUNKS * 1024  # 58368
 
 
 #: above this row count the all-pairs device sweep gives way to LSH
@@ -35,6 +39,12 @@ ALL_PAIRS_LIMIT = 8192
 
 #: signature batch per device pass (gather + minhash)
 SIG_BATCH = 8192
+
+_SIGNATURES = telemetry.counter(
+    "sd_dedup_signatures_total",
+    "MinHash signatures the dedup detector compared, by where each came "
+    "from: stored by the identify page, or gathered and computed here",
+    labels=("source",))
 
 
 def _paths_of(db, rows_db) -> tuple[list[str], list[int]]:
@@ -104,7 +114,8 @@ def find_near_duplicates(library: "Library", location_id: int | None = None,
                          method: str = "auto") -> dict[str, Any]:
     """Similarity groups among sampled-size files. Returns {groups:
     [[file_path rows...]], pairs (each row's best match), pair_count
-    (every verified pair), scanned, method, errors}.
+    (every verified pair), scanned, method, errors, signatures: {reused
+    (stored by the identify page), gathered (read and computed here)}}.
 
     ``method``: ``all_pairs`` (device O(N²K) sweep), ``banded`` (LSH
     candidate buckets + exact verify, corpus-scale), or ``auto`` (all-pairs
@@ -118,25 +129,42 @@ def find_near_duplicates(library: "Library", location_id: int | None = None,
     # job.step); a bare timer outside a job
     trace = telemetry.current_trace()
     db = library.db
-    where = "is_dir = 0 AND size_in_bytes > ?"
+    where = "fp.is_dir = 0 AND fp.size_in_bytes > ?"
     params: list[Any] = [MINIMUM_FILE_SIZE]
     if location_id is not None:
-        where += " AND location_id = ?"
+        where += " AND fp.location_id = ?"
         params.append(location_id)
     with telemetry.span(trace, "dedup.select"):
-        rows_db = [FilePath.decode_row(r) for r in db.query(
-            f"SELECT * FROM file_path WHERE {where} ORDER BY id LIMIT ?",
-            params + [limit])]
-        paths, sizes = _paths_of(db, rows_db)
+        rows_db, stored = [], []
+        for r in db.query(
+                f"SELECT fp.*, om.signature AS _minhash FROM file_path fp "
+                f"LEFT JOIN object_minhash om ON om.object_id = fp.object_id "
+                f"WHERE {where} ORDER BY fp.id LIMIT ?", params + [limit]):
+            row = FilePath.decode_row(r)
+            stored.append(row.pop("_minhash"))
+            rows_db.append(row)
+        reused = [i for i, b in enumerate(stored) if b is not None]
+        gather = [i for i, b in enumerate(stored) if b is None]
+        paths, sizes = _paths_of(db, [rows_db[i] for i in gather])
     n = len(rows_db)
+    counts = {"reused": len(reused), "gathered": len(gather)}
     if n < 2:
         return {"groups": [], "pairs": [], "pair_count": 0, "scanned": n,
-                "errors": [], "method": "none"}
+                "errors": [], "method": "none", "signatures": counts}
     if method == "auto":
         method = "all_pairs" if n <= ALL_PAIRS_LIMIT else "banded"
 
     errors: list[str] = []
-    sigs, lengths = _signatures(paths, sizes, errors)
+    sigs = np.zeros((n, K), np.uint32)
+    valid = np.ones(n, bool)
+    if reused:
+        sigs[reused] = np.frombuffer(
+            b"".join(stored[i] for i in reused), "<u4").reshape(-1, K)
+    if gather:
+        sigs[gather], lengths = _signatures(paths, sizes, errors)
+        valid[gather] = lengths > 0
+    _SIGNATURES.inc(counts["reused"], source="identify")
+    _SIGNATURES.inc(counts["gathered"], source="gather")
     thr_k = max(1, int(threshold * K))
 
     if method == "banded":
@@ -149,13 +177,14 @@ def find_near_duplicates(library: "Library", location_id: int | None = None,
                 f"(requested {threshold}); pairs near the threshold may "
                 "be missed — force method='all_pairs' for exhaustive "
                 "comparison")
-        raw_pairs = _banded_pairs(sigs, lengths > 0, thr_k, errors)
+        raw_pairs = _banded_pairs(sigs, valid, thr_k, errors)
         pair_count = len(raw_pairs)
     else:
-        raw_pairs, pair_count = _all_pairs(sigs, lengths > 0, thr_k)
+        raw_pairs, pair_count = _all_pairs(sigs, valid, thr_k)
 
     with telemetry.span(trace, "dedup.verify"):
-        return _group(rows_db, raw_pairs, pair_count, errors, method)
+        return {**_group(rows_db, raw_pairs, pair_count, errors, method),
+                "signatures": counts}
 
 
 def _group(rows_db: list[dict], raw_pairs: list[tuple[int, int, int]],
@@ -367,7 +396,8 @@ class DedupDetectorJob(StatefulJob):
                 db.insert_many(NearDuplicate, rows, or_ignore=True)
         ctx.progress(message=f"{len(rows)} near-duplicate pairs")
         return StepResult(metadata={"pairs_found": len(rows),
-                                    "scanned": result["scanned"]},
+                                    "scanned": result["scanned"],
+                                    "signatures": result["signatures"]},
                           errors=[str(e) for e in result["errors"]])
 
     def finalize(self, ctx: WorkerContext, data, run_metadata):

@@ -35,7 +35,7 @@ from typing import Any
 from .. import faults, telemetry
 from ..jobs import EarlyFinish, JobError, StatefulJob, StepResult, WorkerContext
 from ..recovery import is_disk_full, note_disk_full
-from ..models import FilePath, Location, Object, utc_now
+from ..models import FilePath, Location, Object, ObjectMinhash, utc_now
 from ..sync.crdt import ref
 from .cas import read_sampled_batch_fast as read_sampled_batch
 from .hasher import HybridHasher, get_hasher
@@ -361,6 +361,9 @@ class FileIdentifierJob(StatefulJob):
         #: the files it would do is pure waste (the gather already ran)
         probe_worthy = sum(1 for r in hashable
                            if r["size_in_bytes"] > MINIMUM_FILE_SIZE) >= 16
+        #: MinHash signatures a device backend computed from the rows it
+        #: hashed, by index into ``hashable`` (the dedup detector reads them)
+        signatures: dict = {}
         with telemetry.span(getattr(ctx, "trace", None), "identifier.hash",
                             files=len(hashable)) as hash_sp:
             try:
@@ -376,6 +379,9 @@ class FileIdentifierJob(StatefulJob):
                     cas_results = hasher.hash_batch(
                         [_abs_path(location_path, r) for r in hashable],
                         [r["size_in_bytes"] for r in hashable])
+                elif getattr(hasher, "USES_DEVICE", False):
+                    cas_results = hasher.hash_gathered(batch["messages"],
+                                                       signatures)
                 else:
                     cas_results = hasher.hash_gathered(batch["messages"])
             except Exception as e:  # noqa: BLE001 — degradation ladder below
@@ -397,6 +403,7 @@ class FileIdentifierJob(StatefulJob):
                 batch["recovered_error"] = repr(e)
                 _RECOVERED.inc()
         batch["cas_results"] = cas_results
+        batch["signatures"] = signatures
         batch["hash_s"] = hash_sp.duration_s
         batch["messages"] = None  # the gather buffers are dead weight now
         if chunk_manifest.manifests_enabled():
@@ -524,14 +531,22 @@ class FileIdentifierJob(StatefulJob):
             db.executemany_noted(
                 "UPDATE file_path SET object_id = ? WHERE id = ?",
                 link_rows, "file_path", (fp_id for _oid, fp_id in link_rows))
+            oid_by_fp = {fp_id: oid for oid, fp_id in link_rows}
 
-            # 4. persist chunk manifests (opt-in) in the SAME transaction —
+            # 4. one MinHash signature per sampled object; an object that
+            # already has one keeps it (equal cas_id, equal signature)
+            sig_rows = [{"object_id": oid_by_fp[hashable[k]["id"]],
+                         "signature": sig.astype("<u4").tobytes()}
+                        for k, sig in (batch.get("signatures") or {}).items()
+                        if hashable[k]["id"] in oid_by_fp]
+            db.insert_many(ObjectMinhash, sig_rows, or_ignore=True)
+
+            # 5. persist chunk manifests (opt-in) in the SAME transaction —
             # a crash between the identify writes and the manifest rows can
             # never surface (the kill matrix pins a SIGKILL here)
             chunked = 0
             if chunk_manifest.manifests_enabled():
                 faults.inject("manifest_commit")
-                oid_by_fp = {fp_id: oid for oid, fp_id in link_rows}
                 items: list[tuple[int, list]] = []
                 seen_oids: set[int] = set()
                 for row, _cas in identified:

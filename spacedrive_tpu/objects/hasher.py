@@ -41,6 +41,9 @@ logger = logging.getLogger(__name__)
 #: largest whole-file message (100KiB + 8B size prefix)
 SMALL_BUCKETS = (1, 4, 16, 32, 64, 101)
 SAMPLED_CHUNKS = (SAMPLED_MESSAGE_LEN + 1023) // 1024  # 57
+#: words of a sampled message's row the MinHash signature reads: the
+#: 57-chunk row the dedup detector gathers into (objects/dedup.py)
+SIGN_WORDS = SAMPLED_CHUNKS * 256
 #: every device call of a bucket hashes a fixed number of rows: at most
 #: BUCKET_MAX_ROWS, and at most BUCKET_CALL_CHUNKS chunks (32 MiB) of rows a
 #: call. One shape per bucket, whatever the page size or the calls before
@@ -132,6 +135,13 @@ class _OutermostGuard:
 _HASH_OUTERMOST = _OutermostGuard()
 
 
+def _is_sampled(message: bytes) -> bool:
+    """A cas message in the sampled layout: its 8-byte size prefix names a
+    file above MINIMUM_FILE_SIZE (a whole file of SAMPLED_MESSAGE_LEN - 8
+    bytes has a message of the same length, and is not one)."""
+    return int.from_bytes(message[:8], "little") > MINIMUM_FILE_SIZE
+
+
 def _message_len(size: int) -> int:
     """Bytes of the cas message actually hashed for a file of ``size``
     (sampled layout caps at SAMPLED_MESSAGE_LEN; whole-file below)."""
@@ -185,7 +195,7 @@ def _paths_bytes(args: tuple) -> int:
 
 
 def _messages_bytes(args: tuple) -> int:
-    (messages,) = args
+    messages = args[0]
     return sum(len(m) for m in messages if not isinstance(m, Exception))
 
 
@@ -202,8 +212,13 @@ class HasherBackend(Protocol):
     def hash_batch(self, paths: list[str | Path],
                    sizes: list[int]) -> list[str | Exception]: ...
 
-    def hash_gathered(self,
-                      messages: list[bytes | Exception]) -> list[str | Exception]: ...
+    def hash_gathered(self, messages: list[bytes | Exception],
+                      signatures: dict | None = None) -> list[str | Exception]:
+        """cas_ids of pre-gathered cas messages. A device backend also puts
+        into ``signatures`` ``{message index: (K,) uint32}`` the MinHash
+        signature of each sampled message it hashed on the device; the
+        identify page passes ``signatures`` to device backends alone, so a
+        host backend may take the messages only."""
 
 
 class CpuHasher:
@@ -350,20 +365,37 @@ class TpuHasher:
             out[indices[j]] = cid
 
     @_count_hash_gathered
-    def hash_gathered(self,
-                      messages: list[bytes | Exception]) -> list[str | Exception]:
+    def hash_gathered(self, messages: list[bytes | Exception],
+                      signatures: dict | None = None) -> list[str | Exception]:
         """Pre-gathered messages through the device bucket path (sampled
         57-chunk messages land in the 64-chunk bucket; same digests as the
-        fused row pipeline, the message is identical either way)."""
-        return _hash_gathered_messages(
-            messages, lambda msgs: _bucketed_hash(msgs, self._hash_bucket))
+        fused row pipeline, the message is identical either way).
+        ``signatures``, when given, receives ``{message index: (K,)
+        uint32}`` for each sampled message (:func:`_is_sampled`)."""
+        ok = [j for j, m in enumerate(messages) if not isinstance(m, Exception)]
+        signed: dict = {}
+        out = _hash_gathered_messages(
+            messages, lambda msgs: _bucketed_hash(msgs, self._hash_bucket,
+                                                  signed))
+        if signatures is not None:
+            signatures.update((ok[k], sig) for k, sig in signed.items())
+        return out
 
     # hooks the sharded variant overrides
     def _hash_bucket(self, msgs: list[bytes], cap: int,
-                     lanes: int) -> list[str]:
-        from ..ops.blake3_jax import blake3_batch_hex
+                     lanes: int) -> tuple[list[str], dict]:
+        """``(hex digests, {row: (K,) uint32 signature})`` of one bucket
+        call. A call that holds a sampled message also computes, from the
+        same device rows, the MinHash signature of each row's first
+        SIGN_WORDS words, and keeps the sampled rows' ones; any other call
+        runs the digest program alone and signs nothing."""
+        from ..ops.blake3_jax import blake3_batch_hex, hash_sign_rows_hex
 
-        return blake3_batch_hex(msgs, max_chunks=cap, lanes=lanes)
+        sampled = [k for k, m in enumerate(msgs) if _is_sampled(m)]
+        if not sampled:
+            return blake3_batch_hex(msgs, max_chunks=cap, lanes=lanes), {}
+        hexes, sigs = hash_sign_rows_hex(msgs, cap, lanes, SIGN_WORDS)
+        return hexes, {k: sigs[k] for k in sampled}
 
     def _pad_lanes(self, n: int) -> int:
         return n
@@ -658,21 +690,27 @@ class HybridHasher:
         return max(60.0, nbytes / self.DEVICE_FLOOR_BPS)
 
     def _dispatch_gathered(self, engine: str, idxs: list[int], messages,
-                           out: list, probe: bool = False) -> None:
+                           out: list, signatures: dict | None,
+                           probe: bool = False) -> None:
         """Run one routed sub-batch: measure the transfer-inclusive rate
         into the router's EWMA; a device failure/timeout finishes the
-        sub-batch natively (byte-identical digests) and degrades the pin."""
+        sub-batch natively (byte-identical digests, no signatures) and
+        degrades the pin."""
         sub = [messages[i] for i in idxs]
         nbytes = sum(len(m) for m in sub)
         t0 = time.perf_counter()
         if engine == "device":
+            signed: dict = {}
             status, res = _bounded_call(
-                lambda: self._tpu.hash_gathered(sub),
+                lambda: self._tpu.hash_gathered(sub, signed),
                 self._device_deadline_s(nbytes, probe),
                 "hybrid-device-dispatch")
             if status == "ok":
                 self.router.observe("device", nbytes,
                                     time.perf_counter() - t0)
+                if signatures is not None:
+                    signatures.update((idxs[k], sig)
+                                      for k, sig in signed.items())
             else:
                 why = repr(res) if status == "error" else \
                     "deadline exceeded (wedged device?)"
@@ -687,17 +725,19 @@ class HybridHasher:
             out[i] = r
 
     @_count_hash_gathered
-    def hash_gathered(self,
-                      messages: list[bytes | Exception]) -> list[str | Exception]:
+    def hash_gathered(self, messages: list[bytes | Exception],
+                      signatures: dict | None = None) -> list[str | Exception]:
         """Gathered-message route: PER-BATCH engine choice by the router
         (live transfer-inclusive rates, hysteresis, bounded re-probe). An
         unprobed process routes native — the safe default on wire-limited
         rigs (the pipelined identifier runs its first batch through
         ``hash_batch`` precisely so the probe seeds the router). With no
         native lib there is nothing to race — mirror hash_batch's routing
-        to the device path, never the python oracle."""
+        to the device path, never the python oracle. ``signatures`` takes
+        what the device slice signed (:meth:`TpuHasher.hash_gathered`);
+        the CPU slice signs nothing."""
         if self._cpu._fast is None:
-            return self._tpu.hash_gathered(messages)
+            return self._tpu.hash_gathered(messages, signatures)
         if self._cpu_rate is None or self._device_rate is None:
             return self._cpu.hash_gathered(messages)
         # mirror hash_batch's small/sampled split — short messages stay on
@@ -720,10 +760,10 @@ class HybridHasher:
             cut = min(self.router.PROBE_SLICE, len(big) // 2)
             if cut > 0:
                 self._dispatch_gathered(probe, big[:cut], messages, out,
-                                        probe=True)
+                                        signatures, probe=True)
                 big = big[cut:]
         if big:
-            self._dispatch_gathered(main, big, messages, out)
+            self._dispatch_gathered(main, big, messages, out, signatures)
         if rest:
             res = self._cpu.hash_gathered([messages[i] for i in rest])
             for i, r in zip(rest, res):
@@ -894,12 +934,13 @@ class ShardedHasher(TpuHasher):
                                               jnp.asarray(lengths))
 
     def _hash_bucket(self, msgs: list[bytes], cap: int,
-                     lanes: int) -> list[str]:
+                     lanes: int) -> tuple[list[str], dict]:
+        """The mesh signs nothing: the dedup detector gathers its files."""
         from ..ops.blake3_jax import hash_rows_hex
         from ..parallel.mesh import sharded_row_hasher
 
         return hash_rows_hex(msgs, cap, self._pad_lanes(lanes),
-                             sharded_row_hasher(self._mesh))
+                             sharded_row_hasher(self._mesh)), {}
 
 
 _BACKENDS: dict[str, Callable[[], HasherBackend]] = {
@@ -989,15 +1030,19 @@ def bucket_rows(cap: int) -> int:
     return rows
 
 
-def _bucketed_hash(messages: list[bytes], hash_bucket) -> list[str]:
+def _bucketed_hash(messages: list[bytes], hash_bucket,
+                   signatures: dict | None = None) -> list[str]:
     """Bucket variable-size cas messages by chunk count and hash each
-    bucket through ``hash_bucket(msgs, cap, lanes)``, in calls of
+    bucket through ``hash_bucket(msgs, cap, lanes) -> (hex digests,
+    {row: signature})``, in calls of
     ``bucket_rows(cap)`` rows (a bucket with more messages takes several
     calls); returns 16-hex cas_ids in input order. The one bucketing
     scheme shared by the local small-file path and the H_HASH service.
     Each call is a ``hash.bucket`` span under the calling
     thread's trace, and is counted by cap (the ``sd_hash_bucket_*``
-    family) once it returns."""
+    family) once it returns. The MinHash signatures the calls return
+    (:meth:`TpuHasher._hash_bucket`) go into ``signatures``, ``{message
+    index: (K,) uint32}``, when it is given."""
     out: list[str | None] = [None] * len(messages)
     buckets: dict[int, list[int]] = {}
     for j, msg in enumerate(messages):
@@ -1015,7 +1060,9 @@ def _bucketed_hash(messages: list[bytes], hash_bucket) -> list[str]:
             with telemetry.span(trace, "hash.bucket", cap=cap,
                                 files=len(msgs), lanes=lanes,
                                 payload_bytes=payload):
-                hexes = hash_bucket(msgs, cap, lanes)
+                hexes, sigs = hash_bucket(msgs, cap, lanes)
+            if signatures is not None:
+                signatures.update((js[k], sig) for k, sig in sigs.items())
             _BUCKET_CALLS.inc(cap=label)
             _BUCKET_FILES.inc(len(msgs), cap=label)
             _BUCKET_LANES.inc(lanes, cap=label)
@@ -1028,9 +1075,13 @@ def _bucketed_hash(messages: list[bytes], hash_bucket) -> list[str]:
 def hash_messages(messages: list[bytes]) -> list[str]:
     """cas_ids for pre-gathered cas messages — the compute side of the
     shared-hasher service (H_HASH): device-bucketed when an accelerator is
-    present, else native C++ BLAKE3, else the Python oracle."""
+    present, else native C++ BLAKE3, else the Python oracle. Its peers keep
+    no MinHash signatures, so its device calls compute none."""
     if _accelerator_available():
-        return _bucketed_hash(messages, TpuHasher()._hash_bucket)
+        from ..ops.blake3_jax import blake3_batch_hex
+
+        return _bucketed_hash(messages, lambda msgs, cap, lanes: (
+            blake3_batch_hex(msgs, max_chunks=cap, lanes=lanes), {}))
     try:
         from ..native import cas_native
 

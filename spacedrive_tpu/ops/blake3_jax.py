@@ -432,17 +432,16 @@ class _RowBuffer:
 _row_buffer = _RowBuffer()
 
 
-def hash_rows_hex(messages: list[bytes], max_chunks: int, lanes: int,
-                  hash_rows) -> list[str]:
-    """Hex digests of B messages: pack them into ``lanes`` >= B rows (the
-    padding lanes hash the empty message), hash the rows on the device
-    through ``hash_rows(rows, lengths) -> (8, lanes)`` digest words, and
-    drop the padding lanes. The host only copies each message into its
-    row; the (block, word, chunk, batch) permutation runs on the device."""
+def _run_rows(messages: list[bytes], max_chunks: int, lanes: int, run):
+    """Pack B messages into ``lanes`` >= B rows (the padding lanes hold the
+    empty message), put them on the device, and read back what
+    ``run(rows, lengths)`` computes from them, as numpy. The host only
+    copies each message into its row; the (block, word, chunk, batch)
+    permutation runs on the device."""
     B = len(messages)
     # one span per stage of the call, under the caller's open span (the
     # identify dispatch thread's pipeline.hash): host packing, the H2D
-    # enqueue, then the kernel through the digests' readback
+    # enqueue, then the kernel through the outputs' readback
     trace = telemetry.current_trace()
     with _row_buffer.take(lanes * max_chunks * CHUNK_LEN) as buf:
         with telemetry.span(trace, "hash.pack", files=B):
@@ -451,8 +450,34 @@ def hash_rows_hex(messages: list[bytes], max_chunks: int, lanes: int,
         with telemetry.span(trace, "hash.put"):
             rows, lengths = jnp.asarray(rows), jnp.asarray(lengths)
         with telemetry.span(trace, "hash.wait"):
-            digests = np.asarray(hash_rows(rows, lengths))
-    return digests_to_hex(digests)[:B]
+            return jax.device_get(run(rows, lengths))
+
+
+def hash_rows_hex(messages: list[bytes], max_chunks: int, lanes: int,
+                  hash_rows) -> list[str]:
+    """Hex digests of B messages, hashed in ``lanes`` rows on the device
+    through ``hash_rows(rows, lengths) -> (8, lanes)`` digest words, the
+    padding lanes dropped."""
+    digests = _run_rows(messages, max_chunks, lanes, hash_rows)
+    return digests_to_hex(digests)[: len(messages)]
+
+
+def hash_sign_rows_hex(messages: list[bytes], max_chunks: int, lanes: int,
+                       sign_words: int) -> tuple[list[str], np.ndarray]:
+    """:func:`hash_rows_hex`, and the (B, K) MinHash signatures
+    (ops/minhash.py) of each message's first ``sign_words`` words, from the
+    same device rows. The signature program is enqueued before the BLAKE3
+    program that takes the rows as a donation, and programs on a device run
+    in the order they were enqueued, so it reads them first."""
+    from .minhash import minhash_rows
+
+    def run(rows, lengths):
+        sigs = minhash_rows(rows, lengths, words=sign_words)
+        return blake3_batch_rows(rows, lengths, donate=True), sigs
+
+    B = len(messages)
+    digests, sigs = _run_rows(messages, max_chunks, lanes, run)
+    return digests_to_hex(digests)[:B], sigs[:B]
 
 
 def blake3_batch_hex(messages: list[bytes], max_chunks: int | None = None,

@@ -6,9 +6,11 @@ detection (BASELINE.json config 4) designed for the TPU:
 
 - **Signatures ride the identify batch.** During file_identifier the sampled
   message rows are already resident on device for BLAKE3; the MinHash kernel
-  reuses them: 8-byte shingles at 8-byte stride, K universal hash functions
-  (odd-multiplier mix on the VPU), min-reduce over shingles. No extra
-  host↔device traffic — the expensive transfer was already paid for cas_id.
+  reuses them (objects/hasher.py ``TpuHasher._hash_bucket``): 8-byte
+  shingles at 8-byte stride, K universal hash functions (odd-multiplier mix
+  on the VPU), min-reduce over shingles. No extra host↔device traffic — the
+  expensive transfer was already paid for cas_id. The dedup detector reads
+  the stored signatures, and gathers only files that have none.
 - **All-pairs compare is blocked compute.** Similarity(i,j) = fraction of
   equal signature components. A lax.scan over row-blocks compares
   (block, N, K) at once — O(N²K) element ops that saturate the VPU while
@@ -50,11 +52,16 @@ def _mix(x: jax.Array) -> jax.Array:
     return x ^ (x >> np.uint32(16))
 
 
-@jax.jit
-def minhash_rows(rows: jax.Array, lengths: jax.Array) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("words",))
+def minhash_rows(rows: jax.Array, lengths: jax.Array,
+                 words: int | None = None) -> jax.Array:
     """Signatures for B messages. ``rows``: (B, W) uint32 — the same row
     layout blake3_batch_rows consumes; ``lengths``: (B,) true byte lengths.
-    Returns (B, K) uint32. Shingle = consecutive u32 pair (8 bytes)."""
+    Returns (B, K) uint32. Shingle = consecutive u32 pair (8 bytes).
+    ``words``: read only each row's first ``words`` words (a message that
+    fits them gets the signature of a row that narrow)."""
+    if words is not None:
+        rows = rows[:, :words]
     B, W = rows.shape
     lo = rows[:, 0::2]  # (B, W/2)
     hi = rows[:, 1::2]

@@ -61,6 +61,15 @@ def _blake3_rows(kernel, lanes=4096, chunks=64):
     return build
 
 
+def _minhash_row_heads(spec):
+    from spacedrive_tpu.objects.hasher import SIGN_WORDS, bucket_rows
+    from spacedrive_tpu.ops.minhash import minhash_rows
+
+    lanes = bucket_rows(64)
+    return (lambda r, n: minhash_rows(r, n, words=SIGN_WORDS),
+            [spec((lanes, 64 * 256), "uint32"), spec((lanes,), "int32")])
+
+
 def _minhash(spec):
     from spacedrive_tpu.objects.dedup import SAMPLED_STRIDE, SIG_BATCH
     from spacedrive_tpu.ops.minhash import minhash_rows
@@ -117,6 +126,9 @@ CASES = {
     # the whole-file bucket of 101 chunks (100 KiB + the size word), the
     # one chunk count not a power of two, at its 256 rows a call
     "blake3_rows_xla_cap101": _blake3_rows("xla", 256, 101),
+    # the signatures of a 64-chunk bucket call that holds a sampled
+    # message, from the first 57 chunks of its rows
+    "minhash_rows_cap64_heads": _minhash_row_heads,
     "minhash_rows": _minhash,
     "similar_pairs_count": _similar_pairs,
     "resize_batch": _resize,

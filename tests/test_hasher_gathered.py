@@ -71,7 +71,7 @@ def test_bucket_edge_page_hashes_every_bucket():
     # the digests are the parametrised test's; here only the routing
     hasher._hash_bucket = lambda msgs, cap, lanes: (
         calls.append((cap, sorted(len(m) - 8 for m in msgs)))
-        or ["0" * 64] * len(msgs))
+        or (["0" * 64] * len(msgs), {}))
     hasher.hash_gathered(_bucket_edge_messages())
     assert calls == [(1, [1016]), (4, [1017, 4088]), (16, [4089, 16376]),
                      (32, [16377, 32760]), (64, [32761, 57344, 65528]),

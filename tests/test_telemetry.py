@@ -303,15 +303,19 @@ CHAIN_SPANS = {
     "indexer": ("indexer.walk", "indexer.rows", "indexer.save"),
     "file_identifier": ("identifier.select", "identifier.gather",
                         "hash.pack", "hash.put", "hash.wait"),
-    "dedup_detector": ("dedup.select", "dedup.gather", "dedup.minhash",
-                       "dedup.sweep", "dedup.verify", "dedup.persist"),
+    "dedup_detector": ("dedup.select", "dedup.sweep", "dedup.verify",
+                       "dedup.persist"),
 }
 
 
-def test_scan_chain_records_every_span(tmp_path, tmp_data_dir, monkeypatch):
-    """indexer → identifier (device hasher, on the CPU here) → dedup: each
-    job's trace totals carry its per-batch spans, and the children sum to
-    no more than the span they nest in."""
+#: the dedup job's spans around its own gather, which opens only for
+#: files with no stored signature
+DEDUP_GATHER_SPANS = ("dedup.gather", "dedup.minhash")
+
+
+def _scan_chain(tmp_path, tmp_data_dir, monkeypatch, hasher):
+    """Scan a tree of six sampled files (one near pair) with ``hasher``;
+    returns the job rows and each chain job's span totals."""
     from spacedrive_tpu.locations import create_location, scan_location
     from spacedrive_tpu.node import Node
 
@@ -329,7 +333,7 @@ def test_scan_chain_records_every_span(tmp_path, tmp_data_dir, monkeypatch):
     node = Node(tmp_data_dir, probe_accelerator=False, watch_locations=False)
     try:
         lib = node.libraries.create("chain-spans")
-        loc = create_location(lib, str(tree), hasher="tpu")
+        loc = create_location(lib, str(tree), hasher=hasher)
         scan_location(lib, loc["id"])
         assert node.jobs.wait_idle(300)
         rows = lib.db.query("SELECT name, metadata FROM job")
@@ -338,9 +342,26 @@ def test_scan_chain_records_every_span(tmp_path, tmp_data_dir, monkeypatch):
     spans = {r["name"]: _decoded(r["metadata"])["trace"]["spans"]
              for r in rows if r["name"] in CHAIN_SPANS}
     assert set(spans) == set(CHAIN_SPANS)
+    return rows, spans
+
+
+def _dedup_meta(rows) -> dict:
+    return next(_decoded(r["metadata"]) for r in rows
+                if r["name"] == "dedup_detector")
+
+
+def test_scan_chain_records_every_span(tmp_path, tmp_data_dir, monkeypatch):
+    """indexer → identifier (device hasher, on the CPU here) → dedup: each
+    job's trace totals carry its per-batch spans, and the children sum to
+    no more than the span they nest in. The identify page signed every
+    file, so the dedup job gathers nothing: no ``dedup.gather`` or
+    ``dedup.minhash`` span opens."""
+    rows, spans = _scan_chain(tmp_path, tmp_data_dir, monkeypatch, "tpu")
     for job, names in CHAIN_SPANS.items():
         for name in names:
             assert spans[job].get(name, {}).get("count", 0) > 0, (job, name)
+    assert not set(DEDUP_GATHER_SPANS) & set(spans["dedup_detector"])
+    assert _dedup_meta(rows)["signatures"] == {"reused": 6, "gathered": 0}
 
     def total(job, name):
         return spans[job][name]["total_s"]
@@ -351,6 +372,21 @@ def test_scan_chain_records_every_span(tmp_path, tmp_data_dir, monkeypatch):
     dedup = sum(total("dedup_detector", n)
                 for n in CHAIN_SPANS["dedup_detector"])
     assert dedup <= total("dedup_detector", "job.step")
+
+
+def test_scan_chain_on_the_cpu_hasher_gathers_in_dedup(
+        tmp_path, tmp_data_dir, monkeypatch):
+    """The same chain with the CPU hasher: the identify page signs
+    nothing, so the dedup job gathers and minhashes every file itself,
+    and those spans nest within its ``job.step`` with the others."""
+    rows, spans = _scan_chain(tmp_path, tmp_data_dir, monkeypatch, "cpu")
+    dedup_spans = spans["dedup_detector"]
+    for name in CHAIN_SPANS["dedup_detector"] + DEDUP_GATHER_SPANS:
+        assert dedup_spans.get(name, {}).get("count", 0) > 0, name
+    assert _dedup_meta(rows)["signatures"] == {"reused": 0, "gathered": 6}
+    dedup = sum(dedup_spans[n]["total_s"]
+                for n in CHAIN_SPANS["dedup_detector"] + DEDUP_GATHER_SPANS)
+    assert dedup <= dedup_spans["job.step"]["total_s"]
 
 
 def _bucket_sums(spans) -> dict:
