@@ -281,6 +281,7 @@ def bench_identify() -> dict:
     the probe sends sampled work to whichever engine measured faster)."""
     import numpy as np
 
+    from spacedrive_tpu.objects.cas import read_sampled_batch
     from spacedrive_tpu.objects.hasher import CpuHasher, HybridHasher
 
     n_files = int(os.environ.get("SD_BENCH_FILES", "2048"))
@@ -298,8 +299,12 @@ def bench_identify() -> dict:
     cpu = CpuHasher()
     cpu_t, cpu_ids = time_best(lambda: cpu.hash_batch(paths, sizes), REPEATS)
     hy = HybridHasher()
-    hy.hash_batch(paths, sizes)  # warmup: compiles kernels + runs the probe
-    hy_t, hy_ids = time_best(lambda: hy.hash_batch(paths, sizes), REPEATS)
+
+    def hybrid():  # the gather is timed with the hash, as cpu's fused path
+        return hy.hash_gathered(read_sampled_batch(paths, sizes))
+
+    hybrid()  # warmup: compiles kernels + runs the probe
+    hy_t, hy_ids = time_best(hybrid, REPEATS)
     if cpu_ids != hy_ids:
         print("FATAL: cas_id mismatch", file=sys.stderr)
         sys.exit(1)
@@ -598,6 +603,7 @@ def bench_scan() -> dict:
     # warm the process-wide hybrid engine off the clock: its one-time probe
     # (XLA kernel compile + link measurement) is per-process, not per-scan,
     # and would otherwise dominate the timed window
+    from spacedrive_tpu.objects.cas import read_sampled_batch
     from spacedrive_tpu.objects.hasher import get_hasher
 
     warm: list[tuple[str, int]] = []
@@ -607,8 +613,8 @@ def bench_scan() -> dict:
             warm.append((str(p), size))
         if len(warm) >= 24:
             break
-    get_hasher("hybrid").hash_batch([p for p, _ in warm],
-                                    [s for _, s in warm])
+    get_hasher("hybrid").hash_gathered(read_sampled_batch(
+        [p for p, _ in warm], [s for _, s in warm]))
 
     # pre-read the tree so both timed passes see the same (warm) page
     # cache — otherwise whichever hasher runs first pays the cold IO and
@@ -781,6 +787,7 @@ def bench_scan_sweep() -> dict:
     # same off-the-clock warmups as bench_scan: the hybrid engine's
     # one-time probe, then the tree into the page cache so every cell
     # sees identical (warm) IO
+    from spacedrive_tpu.objects.cas import read_sampled_batch
     from spacedrive_tpu.objects.hasher import get_hasher
 
     warm: list[tuple[str, int]] = []
@@ -790,8 +797,8 @@ def bench_scan_sweep() -> dict:
             warm.append((str(p), size))
         if len(warm) >= 24:
             break
-    get_hasher("hybrid").hash_batch([p for p, _ in warm],
-                                    [s for _, s in warm])
+    get_hasher("hybrid").hash_gathered(read_sampled_batch(
+        [p for p, _ in warm], [s for _, s in warm]))
     for p in fixture.rglob("*.dat"):
         with open(p, "rb") as fh:
             while fh.read(1 << 20):

@@ -38,7 +38,7 @@ from ..recovery import is_disk_full, note_disk_full
 from ..models import FilePath, Location, Object, ObjectMinhash, utc_now
 from ..sync.crdt import ref
 from .cas import read_sampled_batch_fast as read_sampled_batch
-from .hasher import HybridHasher, get_hasher
+from .hasher import get_hasher
 # imported unconditionally so the sd_chunk_* telemetry families exist on
 # /metrics (and in observability.md's drift gate) even with manifests off
 from . import manifest as chunk_manifest
@@ -173,9 +173,10 @@ class FileIdentifierJob(StatefulJob):
         page = _env_batch_pin() or BATCH_SIZE
         steps = [{"kind": "identify"} for _ in range(-(-count // page))]
         data = {"location_id": location_id, "location_path": location["path"],
-                # hybrid probes both engines and routes to the winner, so a
-                # production scan never takes a known-losing path on hosts
-                # where transfers are slow (the bench measures both regimes)
+                # hybrid probes both engines on the first full page and
+                # routes each batch to the faster, so a production scan
+                # never takes a known-losing path on hosts where transfers
+                # are slow
                 "hasher": location.get("hasher") or "hybrid", "cursor": 0,
                 "sub_path": self.init_args.get("sub_path"),
                 "preview_media":
@@ -352,15 +353,8 @@ class FileIdentifierJob(StatefulJob):
     # -- stage 2: dispatch (device/CPU compute) ------------------------------
     def pipeline_process(self, ctx: WorkerContext, data: dict,
                          batch: dict) -> dict:
-        from .cas import MINIMUM_FILE_SIZE
-
         hasher = get_hasher(data.get("hasher"), node=ctx.node)
         hashable = batch["hashable"]
-        #: _probe_rates needs k = sampled//2 >= 8 files per engine slice —
-        #: below that the fused call can't conclude a probe, so re-reading
-        #: the files it would do is pure waste (the gather already ran)
-        probe_worthy = sum(1 for r in hashable
-                           if r["size_in_bytes"] > MINIMUM_FILE_SIZE) >= 16
         #: MinHash signatures a device backend computed from the rows it
         #: hashed, by index into ``hashable`` (the dedup detector reads them)
         signatures: dict = {}
@@ -368,18 +362,7 @@ class FileIdentifierJob(StatefulJob):
                             files=len(hashable)) as hash_sp:
             try:
                 faults.inject("hash")
-                if getattr(hasher, "_cpu_rate", None) is None \
-                        and isinstance(hasher, HybridHasher) \
-                        and hasher._cpu._fast is not None and probe_worthy:
-                    # unprobed hybrid: run this batch through the fused path
-                    # so the engine probe happens (the gather above left the
-                    # page cache warm); later batches take the gathered
-                    # route with the verdict
-                    location_path = data["location_path"]
-                    cas_results = hasher.hash_batch(
-                        [_abs_path(location_path, r) for r in hashable],
-                        [r["size_in_bytes"] for r in hashable])
-                elif getattr(hasher, "USES_DEVICE", False):
+                if getattr(hasher, "USES_DEVICE", False):
                     cas_results = hasher.hash_gathered(batch["messages"],
                                                        signatures)
                 else:

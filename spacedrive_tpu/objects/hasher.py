@@ -6,17 +6,22 @@ behind the per-location ``hasher`` config ("cpu" | "tpu", BASELINE.json's
 `hasher = "tpu"` flag) so the identifier job, dedup and sync stay
 hasher-agnostic.
 
-The TPU backend batches sampled messages into shape buckets:
-- the fixed 57,352-byte sampled bucket (every file > 100KiB) — the hot path,
-  one compiled kernel shape;
-- a handful of small-file chunk-capacity buckets (1/4/16/32/64/101 chunks) to
-  bound zero-padding waste while keeping the compiled-shape count constant.
+Every backend hashes gathered cas messages (``hash_gathered``): reading
+files into messages is the gather's job (``cas.read_sampled_batch``, the
+identify page), and no backend reads a file. ``CpuHasher.hash_batch``, the
+native fused path-reading reference, is the one exception.
 
-The device compression kernel under every batched path here (row pipeline,
-small-file buckets, sharded variants) is selected by ``SD_BLAKE3_KERNEL=
-xla|pallas`` — resolved per call inside ops/blake3_jax's entry points, so
-the hashers need no plumbing and a process switches kernels without
-re-instantiating backends (each choice jit-caches separately).
+The TPU backend has one device path: it buckets messages by chunk count
+(1/4/16/32/64/101 chunks; every sampled 57,352-byte message of a file
+> 100KiB lands in the 64-chunk bucket), a fixed number of rows a call, so
+the compiled-shape count stays constant. The hybrid probes both engines on
+a page's sampled messages and leaves the verdict to its ``BackendRouter``.
+
+The device compression kernel under every bucket call (single-device and
+sharded) is selected by ``SD_BLAKE3_KERNEL=xla|pallas`` — resolved per call
+inside ops/blake3_jax's entry points, so the hashers need no plumbing and a
+process switches kernels without re-instantiating backends (each choice
+jit-caches separately).
 
 Per-file IO errors come back as Exception entries; callers route them into
 job errors instead of aborting the batch.
@@ -32,8 +37,7 @@ from pathlib import Path
 from typing import Callable, Protocol
 
 from .. import telemetry
-from .cas import (MINIMUM_FILE_SIZE, SAMPLED_MESSAGE_LEN, generate_cas_id,
-                  read_sampled_batch)
+from .cas import MINIMUM_FILE_SIZE, SAMPLED_MESSAGE_LEN, generate_cas_id
 
 logger = logging.getLogger(__name__)
 
@@ -113,9 +117,9 @@ _BUCKET_PAYLOAD = telemetry.counter(
 
 class _OutermostGuard:
     """Process-wide outermost-call tracker (not thread-local: the
-    hybrid's work-stealing branch runs the leaf backends on helper
-    THREADS, and those sub-batches must still attribute to the one
-    hybrid batch). Concurrent independent batches undercount to one —
+    hybrid runs its device slice on a bounded helper THREAD
+    (``_bounded_call``), and that sub-batch must still attribute to the
+    one hybrid batch). Concurrent independent batches undercount to one —
     acceptable: the jobs manager runs one identify at a time per lane."""
 
     def __init__(self) -> None:
@@ -205,12 +209,9 @@ _count_hash_gathered = _instrumented(_messages_bytes)
 
 class HasherBackend(Protocol):
     name: str
-    #: True when hash_batch may touch the jax device backend — get_hasher
-    #: passes the device gate before instantiating such backends
+    #: True when hash_gathered may touch the jax device backend —
+    #: get_hasher passes the device gate before instantiating such backends
     USES_DEVICE: bool
-
-    def hash_batch(self, paths: list[str | Path],
-                   sizes: list[int]) -> list[str | Exception]: ...
 
     def hash_gathered(self, messages: list[bytes | Exception],
                       signatures: dict | None = None) -> list[str | Exception]:
@@ -251,125 +252,18 @@ class CpuHasher:
         return _hash_gathered_messages(messages, _native_hex_batch())
 
 
-#: files per device sub-batch in the pipelined sampled path
-PIPELINE_BATCH = 2048
-
-
 class TpuHasher:
-    """Batched JAX/TPU path.
-
-    Large (sampled) files take the fused pipeline: the native C++ gather
-    reads each file's sample message straight into a row of the device-layout
-    byte matrix (no per-file Python work), the (block,word,chunk,batch)
-    permutation happens on device, and sub-batches are double-buffered so the
-    next gather overlaps the previous batch's transfer+compute (async jax
-    dispatch). Small files go through the bucketed whole-file path.
-    """
+    """Batched JAX/TPU path over gathered cas messages: the bucketed
+    device calls of :func:`_bucketed_hash`."""
 
     name = "tpu"
     USES_DEVICE = True
-
-    @_count_hash_batch
-    def hash_batch(self, paths: list[str | Path], sizes: list[int]) -> list[str | Exception]:
-        from .cas import MINIMUM_FILE_SIZE
-
-        out: list[str | Exception] = [None] * len(paths)  # type: ignore[list-item]
-        sampled = [i for i, s in enumerate(sizes) if s > MINIMUM_FILE_SIZE]
-        small = [i for i, s in enumerate(sizes) if s <= MINIMUM_FILE_SIZE]
-        if sampled:
-            self._hash_sampled(paths, sizes, sampled, out)
-        if small:
-            self._hash_small(paths, sizes, small, out)
-        return out
-
-    # -- sampled (fixed-shape) pipeline ------------------------------------
-    def _hash_sampled(self, paths, sizes, indices: list[int], out: list) -> None:
-        """Fused gather→hash with DOUBLE-BUFFERED H2D: while batch k's
-        kernel executes on device, batch k+1's host gather runs and its
-        rows are already staged device-side (``_stage_rows`` → async
-        ``jax.device_put``), and batch k-1's digests come back. Three
-        batches in flight — transfer is never serialized behind compute,
-        which is exactly where the one-shot r05 device path lost
-        (0.13 GB/s resident vs 0.07 GB/s transfer-inclusive)."""
-        try:
-            from ..native import cas_native
-        except Exception:
-            # no native toolchain: the Python gather into the buckets
-            self._hash_small(paths, sizes, indices, out)
-            return
-
-        import numpy as np
-
-        from ..ops.blake3_jax import _pad_to_tier, digests_to_hex
-
-        stride = SAMPLED_CHUNKS * 1024
-        pending = None  # (device result, host lengths, batch indices)
-        # per sub-batch spans under the caller's open span (the dispatch
-        # thread's pipeline.hash): pack = native gather into the rows,
-        # put = H2D enqueue, wait = kernel enqueue + digest readback
-        trace = telemetry.current_trace()
-
-        def stage(idxs):
-            """Host gather + device staging for one sub-batch (enqueued
-            H2D overlaps whatever kernel is currently running)."""
-            tier = self._pad_lanes(_pad_to_tier(len(idxs)))
-            with telemetry.span(trace, "hash.pack", files=len(idxs)):
-                rows = np.zeros((tier, stride), np.uint8)
-                lengths = np.zeros(tier, np.int32)
-                cas_native.gather_batch([paths[i] for i in idxs],
-                                        [sizes[i] for i in idxs], rows,
-                                        lengths)
-                rows32 = rows.view(np.uint32).reshape(tier, stride // 4)
-            with telemetry.span(trace, "hash.put"):
-                dev_rows, dev_lengths = self._stage_rows(rows32, lengths)
-            return (dev_rows, dev_lengths, lengths, idxs)
-
-        def collect(item):
-            dev, lengths, idxs = item
-            with telemetry.span(trace, "hash.wait"):
-                digests = np.asarray(dev)
-            hexes = digests_to_hex(digests)
-            for j, i in enumerate(idxs):
-                if lengths[j] == 0:
-                    out[i] = OSError(f"cas gather failed for {paths[i]}")
-                else:
-                    out[i] = hexes[j][:16]
-
-        chunks = [indices[s : s + PIPELINE_BATCH]
-                  for s in range(0, len(indices), PIPELINE_BATCH)]
-        staged = stage(chunks[0])
-        for nxt in chunks[1:] + [None]:
-            dev_rows, dev_lengths, lengths, idxs = staged
-            # enqueue batch k's kernel (async jax dispatch) ...
-            with telemetry.span(trace, "hash.wait"):
-                dev = self._device_hash_rows(dev_rows, dev_lengths)
-            # ... then gather + H2D-stage batch k+1 while it runs ...
-            staged = stage(nxt) if nxt is not None else None
-            # ... and only now block on batch k-1's D2H digest readback
-            if pending is not None:
-                collect(pending)
-            pending = (dev, lengths, idxs)
-        if pending is not None:
-            collect(pending)
-
-    # -- small files (variable size, bucketed) -----------------------------
-    def _hash_small(self, paths, sizes, indices: list[int], out: list) -> None:
-        messages = read_sampled_batch([paths[i] for i in indices],
-                                      [sizes[i] for i in indices])
-        ok = [j for j, m in enumerate(messages) if not isinstance(m, Exception)]
-        for j, m in enumerate(messages):
-            if isinstance(m, Exception):
-                out[indices[j]] = m
-        ids = _bucketed_hash([messages[j] for j in ok], self._hash_bucket)
-        for j, cid in zip(ok, ids):
-            out[indices[j]] = cid
 
     @_count_hash_gathered
     def hash_gathered(self, messages: list[bytes | Exception],
                       signatures: dict | None = None) -> list[str | Exception]:
         """Pre-gathered messages through the device bucket path (sampled
-        57-chunk messages land in the 64-chunk bucket; same digests as the
-        fused row pipeline, the message is identical either way).
+        57-chunk messages land in the 64-chunk bucket).
         ``signatures``, when given, receives ``{message index: (K,)
         uint32}`` for each sampled message (:func:`_is_sampled`)."""
         ok = [j for j, m in enumerate(messages) if not isinstance(m, Exception)]
@@ -381,7 +275,7 @@ class TpuHasher:
             signatures.update((ok[k], sig) for k, sig in signed.items())
         return out
 
-    # hooks the sharded variant overrides
+    # the hook the sharded variant overrides
     def _hash_bucket(self, msgs: list[bytes], cap: int,
                      lanes: int) -> tuple[list[str], dict]:
         """``(hex digests, {row: (K,) uint32 signature})`` of one bucket
@@ -396,30 +290,6 @@ class TpuHasher:
             return blake3_batch_hex(msgs, max_chunks=cap, lanes=lanes), {}
         hexes, sigs = hash_sign_rows_hex(msgs, cap, lanes, SIGN_WORDS)
         return hexes, {k: sigs[k] for k in sampled}
-
-    def _pad_lanes(self, n: int) -> int:
-        return n
-
-    def _stage_rows(self, rows32, lengths):
-        """Begin the H2D transfer for a gathered sub-batch (async enqueue;
-        completion overlaps the in-flight kernel). The sharded variant
-        keeps rows on host — the mesh decides placement per shard."""
-        from ..utils.jax_guard import ensure_jax_safe
-
-        ensure_jax_safe()  # the device gate; backends pass it in get_hasher
-        import jax
-
-        return jax.device_put(rows32), jax.device_put(lengths)
-
-    def _device_hash_rows(self, rows32, lengths):
-        import jax.numpy as jnp
-
-        from ..ops.blake3_jax import blake3_batch_rows
-
-        # donate: each staged row buffer is used exactly once (stage() in
-        # _hash_sampled allocates fresh per sub-batch)
-        return blake3_batch_rows(jnp.asarray(rows32), jnp.asarray(lengths),
-                                 donate=True)
 
 
 #: frames of JAX's tracing/lowering/compiling code: a worker standing in
@@ -520,9 +390,16 @@ class BackendRouter:
         self._streak = 0
         self._cpu_since_degrade = 0
 
+    @property
+    def unprobed(self) -> bool:
+        """Neither seeded by a probe nor degraded: no verdict yet (a
+        degraded pin is one — the device lost)."""
+        return (self.cpu_bps is None and self.dev_bps is None
+                and not self.degraded)
+
     def seed(self, cpu_bps: float, dev_bps: float) -> None:
-        """Initialize from the one-time fused probe (both engines measured
-        on real work); the EWMAs take over from here."""
+        """Initialize from the one-time probe (both engines measured on
+        real work); the EWMAs take over from here."""
         with self._lock:
             self.cpu_bps = cpu_bps
             self.dev_bps = dev_bps
@@ -618,17 +495,14 @@ class BackendRouter:
 class HybridHasher:
     """Adaptive heterogeneous executor over the native-CPU and TPU engines.
 
-    On first use it probes each engine's solo throughput on real work (the
-    results are kept, not discarded); the probe SEEDS a
-    :class:`BackendRouter` that then re-picks the engine PER BATCH from
-    live transfer-inclusive rates (EWMA, hysteresis-damped, with periodic
-    exploration of the losing engine and a bounded re-probe out of the
-    degraded pin). On the fused path, when the device holds the route,
-    sampled chunks are work-stolen from one queue with a tail guard so the
-    slower engine's last chunk never dominates the makespan. On rigs where
-    the device loses, ALL sampled work routes native, so hybrid throughput
-    equals the best available engine by construction instead of losing to
-    contention.
+    The first page with enough sampled messages probes each engine's solo
+    throughput on them (the results are kept, not discarded); the probe
+    SEEDS a :class:`BackendRouter`, which holds the verdict and re-picks
+    the engine PER BATCH from live transfer-inclusive rates (EWMA,
+    hysteresis-damped, with periodic exploration of the losing engine and a
+    bounded re-probe out of the degraded pin). On rigs where the device
+    loses, ALL sampled work routes native, so hybrid throughput equals the
+    best available engine by construction instead of losing to contention.
 
     The reference has a single engine (CPU join_all, file_identifier/
     mod.rs:107-134); this seam is where a local-PCIe TPU host gets its
@@ -637,37 +511,23 @@ class HybridHasher:
     name = "hybrid"
     USES_DEVICE = True
 
-    #: steal unit: small enough that the slower engine's last chunk can't
-    #: dominate the makespan, large enough to amortize a device dispatch
-    CHUNK = 128
-    #: files used for the one-time engine rate probe
+    #: sampled messages each engine hashes in the one-time rate probe
     PROBE = 64
 
     def __init__(self) -> None:
         self._tpu = TpuHasher()
         self._cpu = CpuHasher()
-        self._cpu_rate: float | None = None
-        self._device_rate: float | None = None
         #: per-batch engine router (live transfer-inclusive rates + EWMA
-        #: hysteresis); seeded by the one-time fused probe below
+        #: hysteresis); seeded by the one-time probe (:meth:`_probe`)
         self.router = BackendRouter()
 
     def degrade_device(self, reason: str = "") -> None:
         """Pin the route to native CPU after a mid-batch device failure.
         The pin is NOT forever: the router re-probes the device on a
         bounded sub-slice after ``REPROBE_AFTER`` CPU-routed batches."""
-        self._cpu_rate = self._cpu_rate or 1.0
-        self._device_rate = 0.0
         self.router.degrade(reason)
         logger.warning("hybrid hasher degraded to native CPU%s",
                        f": {reason}" if reason else "")
-
-    def _cpu_into(self, paths, sizes, idxs: list[int], out: list) -> None:
-        """Native-CPU hash ``idxs`` and scatter results into ``out``."""
-        res = self._cpu.hash_batch([paths[i] for i in idxs],
-                                   [sizes[i] for i in idxs])
-        for i, r in zip(idxs, res):
-            out[i] = r
 
     #: floor rate for the bounded device deadline: a dispatch slower than
     #: this is indistinguishable from a wedge and gets abandoned
@@ -724,45 +584,97 @@ class HybridHasher:
         for i, r in zip(idxs, res):
             out[i] = r
 
+    def _probe(self, messages, big: list[int], out: list,
+               signatures: dict | None) -> list[int] | None:
+        """Measure both engines on leading slices of this page's sampled
+        messages and seed the router in bytes/s; returns the still-unhashed
+        ones — or None when the page holds too few to measure anything (the
+        router stays unprobed so a fuller page probes; the process-wide
+        hasher must not pin itself to placeholder rates off a tiny page)."""
+        k = min(self.PROBE, len(big) // 2)
+        if k < 8:
+            return None
+        cpu_part, dev_part = big[:k], big[k:2 * k]
+        cpu_msgs = [messages[i] for i in cpu_part]
+        dev_msgs = [messages[i] for i in dev_part]
+        t0 = time.perf_counter()
+        res = self._cpu.hash_gathered(cpu_msgs)
+        cpu_bps = sum(map(len, cpu_msgs)) / max(1e-9, time.perf_counter() - t0)
+        for i, r in zip(cpu_part, res):
+            out[i] = r
+        # the device probe gets a hard deadline: a probe that never
+        # returns would stall every scan — _bounded_call runs it on a
+        # bounded daemon worker (the one wedge-handling policy; compile
+        # time does not count against it)
+        signed: dict = {}
+        t0 = time.perf_counter()
+        status, res = _bounded_call(
+            lambda: self._tpu.hash_gathered(dev_msgs, signed),
+            max(60.0, k * 0.5), "hybrid-device-probe")
+        if status == "ok":
+            dev_bps = sum(map(len, dev_msgs)) / max(
+                1e-9, time.perf_counter() - t0)
+            if signatures is not None:
+                signatures.update((dev_part[j], sig)
+                                  for j, sig in signed.items())
+        else:
+            # timeout, or a dying device: score it dead and finish its
+            # slice on the CPU (the router is seeded, not degraded)
+            logger.warning(
+                "hybrid probe: device engine %s; routing everything to "
+                "native CPU",
+                "unresponsive after deadline" if status == "timeout"
+                else f"failed ({res!r})")
+            res = self._cpu.hash_gathered(dev_msgs)
+            dev_bps = 0.0
+        for i, r in zip(dev_part, res):
+            out[i] = r
+        self.router.seed(cpu_bps, dev_bps)
+        logger.info("hybrid probe: cpu %.2f MB/s, device %.2f MB/s — %s",
+                    cpu_bps / 1e6, dev_bps / 1e6,
+                    "engaging device" if dev_bps > cpu_bps
+                    else "routing to native CPU")
+        return big[2 * k:]
+
     @_count_hash_gathered
     def hash_gathered(self, messages: list[bytes | Exception],
                       signatures: dict | None = None) -> list[str | Exception]:
-        """Gathered-message route: PER-BATCH engine choice by the router
-        (live transfer-inclusive rates, hysteresis, bounded re-probe). An
-        unprobed process routes native — the safe default on wire-limited
-        rigs (the pipelined identifier runs its first batch through
-        ``hash_batch`` precisely so the probe seeds the router). With no
-        native lib there is nothing to race — mirror hash_batch's routing
-        to the device path, never the python oracle. ``signatures`` takes
-        what the device slice signed (:meth:`TpuHasher.hash_gathered`);
-        the CPU slice signs nothing."""
+        """PER-BATCH engine choice by the router (live transfer-inclusive
+        rates, hysteresis, bounded re-probe). While the router is unprobed,
+        the page's sampled messages first carry the probe (:meth:`_probe`);
+        a page too small to probe routes native. With no native lib there
+        is nothing to race — everything goes to the device path, never the
+        python oracle. ``signatures`` takes what the device slice signed
+        (:meth:`TpuHasher.hash_gathered`); the CPU slice signs nothing."""
         if self._cpu._fast is None:
             return self._tpu.hash_gathered(messages, signatures)
-        if self._cpu_rate is None or self._device_rate is None:
-            return self._cpu.hash_gathered(messages)
-        # mirror hash_batch's small/sampled split — short messages stay on
-        # native CPU (IO-bound work whose varied lengths would fan the
-        # device path across many bucket shapes); sampled-class messages
-        # are the routable payload
+        # short messages stay on native CPU (IO-bound work whose varied
+        # lengths would fan the device path across many bucket shapes);
+        # sampled-class messages are the routable payload
         big = [i for i, m in enumerate(messages)
                if not isinstance(m, Exception) and len(m) >= SAMPLED_MESSAGE_LEN]
         if not big:
             return self._cpu.hash_gathered(messages)
-        main, probe = self.router.route()
         big_set = set(big)
         rest = [i for i in range(len(messages)) if i not in big_set]
         out: list[str | Exception] = [None] * len(messages)  # type: ignore[list-item]
-        if probe is not None and probe != main and len(big) > 1:
-            # capped sub-slice on the losing engine keeps its EWMA live
-            # (and is the degraded path's bounded device re-probe) — under
-            # the TIGHT probe deadline, so a collapsed/wedged device costs
-            # seconds, not a generous wedge-detection window
-            cut = min(self.router.PROBE_SLICE, len(big) // 2)
-            if cut > 0:
-                self._dispatch_gathered(probe, big[:cut], messages, out,
-                                        signatures, probe=True)
-                big = big[cut:]
+        if self.router.unprobed:
+            big = self._probe(messages, big, out, signatures)
+            if big is None:
+                return self._cpu.hash_gathered(messages)
         if big:
+            main, probe = self.router.route()
+            if probe is not None and probe != main and len(big) > 1:
+                # capped sub-slice on the losing engine keeps its EWMA
+                # live (and is the degraded path's bounded device
+                # re-probe) — under the TIGHT probe deadline, so a
+                # collapsed/wedged device costs seconds, not a generous
+                # wedge-detection window
+                cut = min(self.router.PROBE_SLICE, len(big) // 2)
+                if cut > 0:
+                    self._dispatch_gathered(probe, big[:cut], messages, out,
+                                            signatures, probe=True)
+                    big = big[cut:]
             self._dispatch_gathered(main, big, messages, out, signatures)
         if rest:
             res = self._cpu.hash_gathered([messages[i] for i in rest])
@@ -770,143 +682,11 @@ class HybridHasher:
                 out[i] = r
         return out
 
-    def _probe_rates(self, paths, sizes, sampled: list[int],
-                     out: list) -> list[int] | None:
-        """Measure both engines on leading slices of the real workload;
-        returns the still-unhashed indices — or None when the batch is too
-        small to measure anything (rates stay unset so a real batch
-        re-probes; the process-wide hasher must not pin itself to
-        placeholder rates off a tiny first batch)."""
-        import time as _time
-
-        k = min(self.PROBE, len(sampled) // 2)
-        if k < 8:
-            return None
-        cpu_part, dev_part, rest = sampled[:k], sampled[k:2 * k], sampled[2 * k:]
-        t0 = _time.perf_counter()
-        self._cpu_into(paths, sizes, cpu_part, out)
-        cpu_rate = k / max(1e-9, _time.perf_counter() - t0)
-        t0 = _time.perf_counter()
-        # the device probe gets a hard deadline: a probe that never
-        # returns would stall every scan — _bounded_call runs it on a
-        # bounded daemon worker (the one wedge-handling policy; compile
-        # time does not count against it)
-        status, err = _bounded_call(
-            lambda: self._tpu._hash_sampled(paths, sizes, dev_part, out),
-            max(60.0, k * 0.5), "hybrid-device-probe")
-        if status == "ok":
-            device_rate = k / max(1e-9, _time.perf_counter() - t0)
-        else:
-            # timeout, or a dying device — either way it must not leave
-            # half-set rates (permanently broken comparisons): score it
-            # dead and finish on CPU (same values, benign overwrite)
-            logger.warning(
-                "hybrid probe: device engine %s; routing everything to "
-                "native CPU",
-                "unresponsive after deadline" if status == "timeout"
-                else f"failed ({err!r})")
-            self._cpu_into(paths, sizes, dev_part, out)
-            device_rate = 0.0
-        # set both rates atomically only once both probes concluded, and
-        # seed the per-batch router's EWMAs (probe files/s × the sampled
-        # message size = transfer-inclusive bytes/s on the probe slices)
-        self._cpu_rate, self._device_rate = cpu_rate, device_rate
-        self.router.seed(cpu_rate * SAMPLED_MESSAGE_LEN,
-                         device_rate * SAMPLED_MESSAGE_LEN)
-        logger.info("hybrid probe: cpu %.0f files/s, device %.0f files/s — %s",
-                    self._cpu_rate, self._device_rate,
-                    "engaging device" if self._device_rate > self._cpu_rate
-                    else "routing to native CPU")
-        return rest
-
-    @_count_hash_batch
-    def hash_batch(self, paths: list[str | Path], sizes: list[int]) -> list[str | Exception]:
-        import queue as _q
-        import threading
-
-        from .cas import MINIMUM_FILE_SIZE
-
-        n = len(paths)
-        out: list[str | Exception] = [None] * n  # type: ignore[list-item]
-        sampled = [i for i, s in enumerate(sizes) if s > MINIMUM_FILE_SIZE]
-        small = [i for i, s in enumerate(sizes) if s <= MINIMUM_FILE_SIZE]
-        if small:  # small files: native CPU batch (IO-bound, not worth device)
-            self._cpu_into(paths, sizes, small, out)
-
-        if not sampled:
-            return out
-        if self._cpu._fast is None:  # no native lib: nothing to race
-            self._tpu._hash_sampled(paths, sizes, sampled, out)
-            return out
-
-        if self._cpu_rate is None:
-            rest = self._probe_rates(paths, sizes, sampled, out)
-            if rest is None:  # too small to probe — CPU for THIS batch only
-                self._cpu_into(paths, sizes, sampled, out)
-                return out
-            sampled = rest
-            if not sampled:
-                return out
-
-        if self._device_rate <= self._cpu_rate:
-            self._cpu_into(paths, sizes, sampled, out)
-            return out
-
-        work: _q.Queue[list[int]] = _q.Queue()
-        for start in range(0, len(sampled), self.CHUNK):
-            work.put(sampled[start : start + self.CHUNK])
-
-        # this branch only runs when the device won the probe, so the CPU is
-        # the slower engine here — the tail guard (slower engine never takes
-        # one of the last chunks, or its chunk latency becomes the makespan)
-        # belongs on the CPU worker
-        def cpu_worker():
-            while True:
-                if work.qsize() < 2:
-                    return
-                try:
-                    idxs = work.get_nowait()
-                except _q.Empty:
-                    return
-                self._cpu_into(paths, sizes, idxs, out)
-
-        def tpu_worker():
-            while True:
-                try:
-                    idxs = work.get_nowait()
-                except _q.Empty:
-                    return
-                try:
-                    self._tpu._hash_sampled(paths, sizes, idxs, out)
-                except Exception:
-                    # device died mid-batch: return the chunk to the queue
-                    # and stop stealing — the drain below finishes natively
-                    logger.exception("hybrid device worker failed mid-batch")
-                    work.put(idxs)
-                    return
-
-        threads = [threading.Thread(target=cpu_worker, daemon=True),
-                   threading.Thread(target=tpu_worker, daemon=True)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        # drain of last resort: anything still queued (device died, CPU
-        # stopped at the tail guard) is hashed natively so every index gets
-        # a result — the list[str | Exception] contract allows no Nones
-        while True:
-            try:
-                idxs = work.get_nowait()
-            except _q.Empty:
-                break
-            self._cpu_into(paths, sizes, idxs, out)
-        return out
-
 
 class ShardedHasher(TpuHasher):
     """Multi-device variant: batch axis sharded over a data-parallel mesh
-    (parallel/mesh.py) for both the row pipeline and the small-file buckets;
-    lane counts pad to a multiple of the mesh's data-axis size."""
+    (parallel/mesh.py) for the bucket calls; lane counts pad to a multiple
+    of the mesh's data-axis size."""
 
     name = "tpu-sharded"
 
@@ -919,19 +699,6 @@ class ShardedHasher(TpuHasher):
         from ..parallel.mesh import pad_batch_for_mesh
 
         return pad_batch_for_mesh(n, self._mesh)
-
-    def _stage_rows(self, rows32, lengths):
-        # host-side staging: the sharded row hasher shards the batch axis
-        # itself; a premature single-device put would just be resharded
-        return rows32, lengths
-
-    def _device_hash_rows(self, rows32, lengths):
-        import jax.numpy as jnp
-
-        from ..parallel.mesh import sharded_row_hasher
-
-        return sharded_row_hasher(self._mesh)(jnp.asarray(rows32),
-                                              jnp.asarray(lengths))
 
     def _hash_bucket(self, msgs: list[bytes], cap: int,
                      lanes: int) -> tuple[list[str], dict]:
@@ -1145,11 +912,6 @@ class RemoteHasher:
         if cur:
             batches.append(cur)
         return batches
-
-    @_count_hash_batch
-    def hash_batch(self, paths: list[str | Path],
-                   sizes: list[int]) -> list[str | Exception]:
-        return self.hash_gathered(read_sampled_batch(paths, sizes))
 
     @_count_hash_gathered
     def hash_gathered(self,

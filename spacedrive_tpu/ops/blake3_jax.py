@@ -289,8 +289,8 @@ def blake3_batch_rows(rows: jax.Array, lengths: jax.Array,
     where a 120MB transpose is ~free, instead of in a host numpy transpose
     that used to dominate the pipeline profile.
 
-    ``donate=True`` (the hasher pipeline's fused path) DONATES the row
-    buffer on a real accelerator: each staged sub-batch is ``device_put``
+    ``donate=True`` (the hasher's bucket calls) DONATES the row
+    buffer on a real accelerator: each call's rows are ``device_put``
     once and never touched again, so XLA may reuse the (tier × 57KiB)
     transfer buffer as scratch instead of allocating per batch —
     double-buffered H2D without doubling resident memory. Donation is

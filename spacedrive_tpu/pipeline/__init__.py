@@ -3,8 +3,8 @@ and commit across job steps.
 
 PR 2 proved the kernel; BENCH_r05 proved the kernel alone does not move
 ``scan_e2e_files_per_sec`` — every step of a batched job ran strictly
-sequentially (SELECT → gather → hash → transaction), so the double-buffering
-inside ``TpuHasher._hash_sampled`` died at each step boundary. "GPUs as
+sequentially (SELECT → gather → hash → transaction), so the device's
+overlap inside one hash call died at each step boundary. "GPUs as
 Storage System Accelerators" (arxiv 1202.3669) and SEDD (arxiv 2501.01046)
 both find that accelerator storage pipelines only win when I/O, transfer and
 compute overlap *end-to-end*; this package is that layer.

@@ -131,72 +131,72 @@ def test_pack_rows_layout():
 
 def test_hybrid_hasher_adaptive_routing(tmp_path):
     """HybridHasher: byte-exact results across the probe and both routing
-    outcomes; forcing the device-rate verdict either way must not change
+    outcomes; forcing the router's verdict either way must not change
     correctness."""
-    import random
-
-    from spacedrive_tpu.objects.cas import generate_cas_id
+    from spacedrive_tpu.objects.cas import generate_cas_id, read_sampled_batch
     from spacedrive_tpu.objects.hasher import HybridHasher
 
     rng = random.Random(9)
     paths, sizes = [], []
-    for i in range(40):
+    for i in range(40):  # 20 sampled-class: enough to carry the probe
         size = rng.choice([500, 50_000, 150_000, 200_000])
         p = tmp_path / f"h{i}.bin"
         p.write_bytes(rng.randbytes(size))
         paths.append(str(p))
         sizes.append(size)
     expect = [generate_cas_id(p, s) for p, s in zip(paths, sizes)]
+    messages = read_sampled_batch(paths, sizes)
 
     hy = HybridHasher()
-    got = hy.hash_batch(paths, sizes)  # runs the probe inline
-    assert got == expect
-    assert hy._cpu_rate is not None and hy._device_rate is not None
+    assert hy.router.unprobed
+    assert hy.hash_gathered(messages) == expect  # runs the probe inline
+    assert not hy.router.unprobed
+    assert hy.router.cpu_bps > 0 and hy.router.dev_bps is not None
 
     # force both verdicts and re-hash
-    hy._device_rate = 0.0
-    assert hy.hash_batch(paths, sizes) == expect
-    hy._device_rate = hy._cpu_rate * 10
-    assert hy.hash_batch(paths, sizes) == expect
+    hy.router.seed(1.0, 0.0)
+    assert hy.hash_gathered(messages) == expect
+    hy.router.seed(1.0, 10.0)
+    assert hy.hash_gathered(messages) == expect
 
 
 def test_hybrid_router_provably_picks_fastest(tmp_path, monkeypatch):
     """The router's core guarantee (tpu-backend.md, ceiling section): when
-    the device engine loses the probe, NO sampled work is dispatched to it
+    the CPU holds the route, NO sampled message is dispatched to the device
     — hybrid throughput equals the best engine by construction — and when
-    it wins, stolen chunks plus the drain still cover every file."""
-    import random
-
+    the device holds it, only sampled messages reach it and every file
+    still gets its cas_id."""
     from spacedrive_tpu.objects import hasher as hmod
-    from spacedrive_tpu.objects.cas import generate_cas_id
+    from spacedrive_tpu.objects.cas import generate_cas_id, read_sampled_batch
 
     rng = random.Random(11)
     paths, sizes = [], []
     for i in range(30):
-        size = 150_000 + i  # all sampled-class
+        size = 150_000 + i if i % 5 else 500 + i  # 24 sampled, 6 small
         p = tmp_path / f"r{i}.bin"
         p.write_bytes(rng.randbytes(size))
         paths.append(str(p))
         sizes.append(size)
     expect = [generate_cas_id(p, s) for p, s in zip(paths, sizes)]
+    messages = read_sampled_batch(paths, sizes)
 
     hy = hmod.HybridHasher()
     device_calls = []
 
-    def spy(paths_, sizes_, idxs, out):
-        device_calls.append(list(idxs))
-        hy._cpu_into(paths_, sizes_, idxs, out)  # correct values, fake engine
+    def spy(msgs, signatures=None):
+        device_calls.append(list(msgs))
+        return hy._cpu.hash_gathered(msgs)  # correct values, fake engine
 
-    monkeypatch.setattr(hy._tpu, "_hash_sampled", spy)
+    monkeypatch.setattr(hy._tpu, "hash_gathered", spy)
 
-    # device lost the probe: the sampled set must never reach the device
-    hy._cpu_rate, hy._device_rate = 1000.0, 10.0
-    assert hy.hash_batch(paths, sizes) == expect
+    # the CPU won: the sampled set must never reach the device
+    hy.router.seed(1000.0, 10.0)
+    assert hy.hash_gathered(messages) == expect
     assert device_calls == []
 
-    # device won the probe: it participates (only on sampled indices), and
+    # the device won: it takes the sampled messages, and only those, and
     # every index still resolves to the right cas_id
-    hy._cpu_rate, hy._device_rate = 10.0, 1000.0
-    assert hy.hash_batch(paths, sizes) == expect
-    stolen = {i for chunk in device_calls for i in chunk}
-    assert stolen and stolen <= set(range(len(paths)))
+    hy.router.seed(10.0, 1000.0)
+    assert hy.hash_gathered(messages) == expect
+    sent = [m for call in device_calls for m in call]
+    assert sent and all(len(m) == SAMPLED_MESSAGE_LEN for m in sent)

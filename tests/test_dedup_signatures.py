@@ -133,7 +133,6 @@ def test_hybrid_device_slice_signs(tmp_path):
     hy = HybridHasher()
     if hy._cpu._fast is None:
         pytest.skip("no native CPU hasher to route against")
-    hy._cpu_rate, hy._device_rate = 1.0, 2.0
     hy.router.seed(1.0, 2.0)  # the device holds the route
     signatures = {}
     assert hy.hash_gathered(messages, signatures) == \

@@ -551,12 +551,14 @@ def test_sync_apply_crash_falls_back_to_careful_pass(tmp_path, clean_faults):
 
 def test_hybrid_degrade_flips_verdict():
     h = hasher_mod.HybridHasher()
-    h._cpu_rate, h._device_rate = 10.0, 99.0
+    assert h.router.unprobed
     h.router.seed(10.0, 99.0)
-    assert h.router.current == "device"
+    assert h.router.current == "device" and not h.router.unprobed
     h.degrade_device("unit")
-    assert h._device_rate == 0.0 and h._cpu_rate == 10.0
+    assert h.router.dev_bps == 0.0 and h.router.cpu_bps == 10.0
     assert h.router.current == "cpu" and h.router.degraded
+    # a degraded router holds a verdict: the next page does not re-probe
+    assert not h.router.unprobed
 
 
 def test_bounded_call_does_not_count_compile_time(monkeypatch):

@@ -5,7 +5,8 @@ gathered: sampled 57,352-byte messages of large files, whole-file messages
 of small ones, and gather failures, which pass through in place. The
 single-device and the mesh-sharded hasher must give the CPU path's cas_ids
 position by position (the sharded one on the suite's 8 virtual devices),
-and the program's reference BLAKE3's.
+and the program's reference BLAKE3's. The hybrid hasher probes its
+engines on those same messages and reads no file itself.
 
 The bucket-edge page holds the cas message of a file at each edge of a
 chunk-capacity bucket (size + 8 bytes fills 1, 4, 16, 32, 64 or 101
@@ -14,13 +15,16 @@ and one sampled file: one call hashes every bucket.
 """
 
 import random
+from pathlib import Path
 
 import pytest
 
 from spacedrive_tpu.objects.blake3_ref import blake3
 from spacedrive_tpu.objects.cas import (SAMPLED_MESSAGE_LEN,
-                                        cas_message_from_bytes)
-from spacedrive_tpu.objects.hasher import CpuHasher, ShardedHasher, TpuHasher
+                                        cas_message_from_bytes,
+                                        generate_cas_id, read_sampled_batch)
+from spacedrive_tpu.objects.hasher import (CpuHasher, HybridHasher,
+                                           ShardedHasher, TpuHasher)
 
 #: file sizes at each bucket edge and one byte past it, up to the
 #: whole-file limit (100 KiB)
@@ -105,3 +109,105 @@ def test_bucket_calls_do_not_depend_on_earlier_calls():
     page(1500)
     assert calls == [(1, 1024, 1024)] * 4 + [(1, 1024, 1024), (1, 476, 1024)]
     assert _blake3_batch_rows_impl._cache_size() == compiled
+
+
+def _refuse(*_a, **_k):
+    raise AssertionError("a hasher read a file")
+
+
+def _write_tree(tree, sizes, seed) -> list[str]:
+    rng = random.Random(seed)
+    tree.mkdir(parents=True)
+    paths = []
+    for i, n in enumerate(sizes):
+        p = tree / f"f{i:02d}.bin"
+        p.write_bytes(rng.randbytes(n))
+        paths.append(str(p))
+    return paths
+
+
+def test_hybrid_probe_runs_on_gathered_messages(tmp_path, monkeypatch):
+    """An unprobed hybrid probes both engines on one page of gathered
+    messages: the router is seeded, the ids are the CPU oracle's, the
+    device slice signs, and no file is read again — the files are gone,
+    and the fused CPU path and the native gather refuse."""
+    from spacedrive_tpu.native import cas_native
+
+    sizes = [150_000 + i for i in range(20)] + [500, 40_000, 1]
+    paths = _write_tree(tmp_path / "t", sizes, 28)
+    expect = [generate_cas_id(p, n) for p, n in zip(paths, sizes)]
+    messages = read_sampled_batch(paths, sizes)
+    failed = OSError("gather failed: g.bin")
+    messages.append(failed)
+    for path in paths:
+        Path(path).unlink()
+    monkeypatch.setattr(cas_native, "gather_batch", _refuse)
+    monkeypatch.setattr(cas_native, "hash_batch", _refuse)
+    monkeypatch.setattr(CpuHasher, "hash_batch", _refuse)
+
+    hy = HybridHasher()
+    assert hy._cpu._fast is _refuse and hy.router.unprobed
+    signatures: dict = {}
+    got = hy.hash_gathered(messages, signatures)
+    assert got[:-1] == expect and got[-1] is failed
+    assert not hy.router.unprobed and not hy.router.degraded
+    assert hy.router.cpu_bps > 0 and hy.router.dev_bps > 0
+    # k = 10 messages a engine: the device's are signed, the CPU's not
+    assert len(signatures) >= 10 and set(signatures) <= set(range(20))
+
+
+def test_hybrid_scan_reads_each_file_once(tmp_path, tmp_data_dir,
+                                          monkeypatch):
+    """A ``hasher="hybrid"`` scan whose first page carries the probe
+    gathers each file once in the identify job (no second read of the
+    probe page), and commits the files a ``hasher="cpu"`` scan of the same
+    tree commits."""
+    from spacedrive_tpu.locations import create_location, scan_location
+    from spacedrive_tpu.node import Node
+    from spacedrive_tpu.objects import file_identifier, hasher
+
+    sizes = [150_000 + 7 * i for i in range(24)] + [300 + i for i in range(8)]
+    paths = _write_tree(tmp_path / "tree", sizes, 29)
+    (tmp_path / "tree" / "copy.bin").write_bytes(Path(paths[0]).read_bytes())
+    paths.append(str(tmp_path / "tree" / "copy.bin"))
+
+    reads: list[list[str]] = []
+    gather = file_identifier.read_sampled_batch
+
+    def counted(batch_paths, *args, **kwargs):
+        reads.append([str(p) for p in batch_paths])
+        return gather(batch_paths, *args, **kwargs)
+
+    fused: list = []
+
+    def fused_read(self, batch_paths, sizes):
+        fused.append(batch_paths)  # the identify swallows a hasher's raise
+        raise AssertionError("the fused path read a file")
+
+    monkeypatch.setattr(file_identifier, "read_sampled_batch", counted)
+    monkeypatch.setattr(CpuHasher, "hash_batch", fused_read)
+    hy = HybridHasher()
+    monkeypatch.setitem(hasher._instances, "hybrid", hy)
+
+    def scan(lib, name):
+        loc = create_location(lib, str(tmp_path / "tree"), hasher=name)
+        scan_location(lib, loc["id"])
+        assert node.jobs.wait_idle(300)
+        return lib.db.query(
+            "SELECT f.materialized_path, f.name, f.size_in_bytes, f.cas_id, "
+            "o.kind FROM file_path f JOIN object o ON o.id = f.object_id "
+            "WHERE f.is_dir = 0 ORDER BY f.materialized_path, f.name")
+
+    node = Node(tmp_data_dir, probe_accelerator=False, watch_locations=False)
+    try:
+        hybrid_rows = scan(node.libraries.create("hybrid"), "hybrid")
+        read = sorted(p for batch in reads for p in batch)
+        assert read == sorted(str(Path(p).resolve()) for p in paths)
+        assert fused == []
+        assert not hy.router.unprobed
+        cpu_rows = scan(node.libraries.create("cpu"), "cpu")
+    finally:
+        node.shutdown()
+    assert len(hybrid_rows) == len(paths)
+    assert [dict(r) for r in hybrid_rows] == [dict(r) for r in cpu_rows]
+    assert hybrid_rows[0]["cas_id"] == generate_cas_id(paths[0], sizes[0])

@@ -682,7 +682,7 @@ def test_remote_hasher_service(two_nodes, tmp_path):
     ships locally-gathered cas messages to a peer advertising an
     accelerator and gets byte-exact cas_ids back; non-members are refused;
     remote failure falls back to the local engine."""
-    from spacedrive_tpu.objects.cas import generate_cas_id
+    from spacedrive_tpu.objects.cas import generate_cas_id, read_sampled_batch
     from spacedrive_tpu.objects.hasher import RemoteHasher
 
     a, b = two_nodes
@@ -706,12 +706,14 @@ def test_remote_hasher_service(two_nodes, tmp_path):
         files.append((p, size))
 
     hasher = RemoteHasher(b)
-    ids = hasher.hash_batch([p for p, _ in files], [s for _, s in files])
+    ids = hasher.hash_gathered(read_sampled_batch(
+        [p for p, _ in files], [s for _, s in files]))
     assert ids == [generate_cas_id(p, s) for p, s in files]
 
     # a vanished file surfaces as an exception, others still hash
     missing = tmp_path / "gone.bin"
-    mixed = hasher.hash_batch([files[0][0], missing], [files[0][1], 64])
+    mixed = hasher.hash_gathered(read_sampled_batch(
+        [files[0][0], missing], [files[0][1], 64]))
     assert mixed[0] == ids[0] and isinstance(mixed[1], Exception)
 
     # an unpaired third node is refused service by a
@@ -733,14 +735,15 @@ def test_remote_hasher_service(two_nodes, tmp_path):
     # no accelerator peers visible -> silent local fallback, same ids
     a.config.write(accelerator={"kind": None, "devices": 0, "mesh": []})
     hasher_local = RemoteHasher(c)  # c has no p2p loop anymore: forces fallback
-    ids2 = hasher_local.hash_batch([p for p, _ in files], [s for _, s in files])
+    ids2 = hasher_local.hash_gathered(read_sampled_batch(
+        [p for p, _ in files], [s for _, s in files]))
     assert ids2 == ids
 
 
 def test_remote_hasher_splits_wire_batches(two_nodes, tmp_path):
     """A batch whose cas messages exceed WIRE_BATCH_BYTES must split into
     multiple H_HASH requests and still return byte-exact ids in order."""
-    from spacedrive_tpu.objects.cas import generate_cas_id
+    from spacedrive_tpu.objects.cas import generate_cas_id, read_sampled_batch
     from spacedrive_tpu.objects.hasher import RemoteHasher
 
     a, b = two_nodes
@@ -765,7 +768,7 @@ def test_remote_hasher_splits_wire_batches(two_nodes, tmp_path):
                                    [b"x" * 57352] * 40)
     assert len(batches) >= 3
 
-    ids = hasher.hash_batch(paths, sizes)
+    ids = hasher.hash_gathered(read_sampled_batch(paths, sizes))
     assert ids == [generate_cas_id(p, s) for p, s in zip(paths, sizes)]
 
 
